@@ -43,6 +43,7 @@ from benchmarks.paper_tables import (  # noqa: E402
     bench_serving_results_match,
     bench_vectorized,
 )
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 # §15.3 gate: host readout's share of one batch's phase-bracketed wall time.
 # The §15.1 device-side assembly + lazy materialization must keep the host's
@@ -59,6 +60,7 @@ def main() -> None:
         help="write the serving comparison to BENCH_serving.json",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     n_queries = 10 if args.quick else 30
 
     print("name,us_per_call,derived")

@@ -37,7 +37,9 @@ from typing import Any, Callable
 
 import numpy as np
 
-import jax
+# jax is imported only inside the pytree functions: the index store and the
+# bulk-ingest spawn workers import this module and stay numpy-only, so a
+# worker never loads a JAX backend next to the process that holds the chip.
 
 __all__ = [
     "save_checkpoint",
@@ -144,6 +146,8 @@ def _numbered(directory: Path, prefix: str) -> list[tuple[int, Path]]:
 
 
 def _flatten(tree: Any) -> tuple[list[np.ndarray], Any]:
+    import jax
+
     leaves, treedef = jax.tree.flatten(tree)
     return [np.asarray(l) for l in leaves], treedef
 
@@ -188,6 +192,8 @@ def restore_checkpoint(
     """Restore into the structure of ``template``; optionally re-shard with
     ``shardings`` (a pytree of NamedSharding for the *current* mesh —
     elastic resume, DESIGN.md §12.4)."""
+    import jax
+
     directory = Path(directory)
     step = step if step is not None else latest_step(directory)
     if step is None:
@@ -217,6 +223,8 @@ class CheckpointManager:
         self._error: BaseException | None = None
 
     def save_async(self, step: int, payload: Any) -> None:
+        import jax
+
         self.wait()  # one in flight at a time (double buffering)
         host = jax.tree.map(np.asarray, jax.device_get(payload))
 

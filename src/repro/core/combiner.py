@@ -7,7 +7,8 @@ intermediate per-lemma posting lists**:
 Step 1 (§8)  — document alignment: advance the min-doc iterator until every
                iterator sits on the same document.
 Step 2 (§9)  — position alignment inside the document: advance the
-               min-position iterator until ``maxP - minP < 2*MaxDistance``.
+               min-position iterator until ``maxP - minP <= 4*MaxDistance``
+               (fidelity notes).
 Step 3 (§10) — the Position table: three cyclic buffers of ``WindowSize``
                entries, each with a 64-bit occupancy ``Mask``.  ``Set(P,Lem)``
                writes the entry at relative position ``P - Start``; Bit Scan
@@ -32,7 +33,17 @@ Fidelity notes (see DESIGN.md §7):
   overwrite, and the §10.1 completion check runs once per position (all of
   the position's events enter the Lemma table first) — exactly the oracle's
   atomic-position sweep, so SE2.4 stays fragment-identical to
-  ``core/oracle.py`` and every device engine.
+  ``core/oracle.py`` and every device engine;
+* the §9 Step-2 gate compares the keys' *anchor* positions (a record's
+  first component), but a record's other components lie up to MaxDistance
+  either side of its anchor.  A fragment of span ``<= 2*MaxDistance`` can
+  therefore draw on records whose anchors are up to ``4*MaxDistance``
+  apart, and the verbatim ``< 2*MaxDistance`` gate skips them and loses
+  the fragment.  The gate is ``<= 4*MaxDistance``: every key contributes
+  a lemma no other key covers unstarred (``select_keys``), so a skipped
+  record can share no reportable fragment with the far iterator, and
+  SE2.4 equals the oracle (needs ``4*MaxDistance < WindowSize``, the
+  read-ahead of one Step-3 round).
 """
 
 from __future__ import annotations
@@ -309,6 +320,10 @@ def se24_combiner(
     key_list = list(keys) if keys is not None else select_keys(subquery, index.fl)
     iters = [KeyIterator(k, index.key_postings(k.components), stats) for k in key_list]
     max_span = 2 * D
+    # Step 2 compares the keys' anchor positions, but a record's components
+    # lie up to D either side of its anchor: two records whose events share
+    # a fragment of span <= 2D can have anchors 4D apart (module notes)
+    step2_gap = 4 * D
     results: list[SearchResult] = []
 
     while True:
@@ -324,7 +339,7 @@ def se24_combiner(
             ps = [it.pos for it in in_doc]
             stats.heap_ops += 1
             delta = max(ps) - min(ps)
-            if delta < 2 * D:
+            if delta <= step2_gap:
                 _step3(doc, iters, state, max_span)  # Step 3, then back here
                 continue
             # advance the min-position iterator

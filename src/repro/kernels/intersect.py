@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+
 __all__ = ["intersect_sorted", "block_offsets"]
 
 PAD = np.int32(2**31 - 1)
@@ -61,7 +63,7 @@ def intersect_sorted(
     block_a: int = 128,
     block_b: int = 256,
     n_chunks: int = 2,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """1/0 membership of each ``a`` element in ``b``.
 
@@ -92,6 +94,6 @@ def intersect_sorted(
             out_specs=pl.BlockSpec((1, block_a), lambda i, j, off: (0, i)),
         ),
         out_shape=jax.ShapeDtypeStruct((1, na), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(offsets, a[None, :], b[None, :])
     return out[0]

@@ -1,7 +1,7 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret=True`` everywhere in this repo (CPU container); on a real TPU
-deployment the same calls run compiled — the flag is plumbed through configs.
+The kernels compile on a TPU and run in the Pallas interpreter on a CPU
+backend (``kernels/backend.py``); callers never choose the mode.
 """
 
 from __future__ import annotations
@@ -35,14 +35,13 @@ __all__ = [
 
 @functools.partial(
     jax.jit,
-    static_argnames=("max_distance", "use_kernel", "interpret", "compute_dtype"),
+    static_argnames=("max_distance", "use_kernel", "compute_dtype"),
 )
 def proximity_search_scores(
     occ: jax.Array,  # [B, L, N] occupancy per candidate window
     mult: jax.Array,  # [B, L]
     max_distance: int,
     use_kernel: bool = False,
-    interpret: bool = True,
     compute_dtype: str = "int32",
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused cover + §14 relevance: returns (emit, start, scores[B]).
@@ -53,7 +52,7 @@ def proximity_search_scores(
     cdt = jnp.dtype(compute_dtype)
     if use_kernel:
         emit, start = proximity_window(
-            occ, mult, max_distance, interpret=interpret, compute_dtype=compute_dtype
+            occ, mult, max_distance, compute_dtype=compute_dtype
         )
     else:
         emit, start = proximity_window_ref(occ.astype(cdt), mult, max_distance)

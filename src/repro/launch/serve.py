@@ -157,6 +157,10 @@ def main() -> None:
     import time
     from pathlib import Path
 
+    from ..runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.connect:
         from ..search.service import request_over_tcp
 
@@ -322,6 +326,10 @@ def main() -> None:
     # warm through the REAL serving path with the actual query slate and
     # top_k: shape budgets and top_k are static device-program arguments,
     # so this compiles exactly the programs the first served round reuses
+    import jax
+
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}")
     warm = frontend.warmup(queries=args.queries, top_k=args.top_k)
     print(f"warmup: precompiled {warm['programs']} device program(s) in "
           f"{warm['seconds'] * 1000:.0f} ms (cold p99 excludes jit compile)")

@@ -23,7 +23,8 @@ generation** and does the gather/pack there:
   raw rows enumerate occurrence pairs (O(occ³) per document) while the
   distinct positions per slot are O(occ).  Streams are concatenated
   (``index.store.family_rows`` key order, every extent aligned to
-  ``ARENA_BLOCK`` rows) into ONE int32 device buffer per family.  A
+  ``ARENA_BLOCK`` rows) into ONE field-major ``[2, rows]`` int32 device
+  buffer per family (``kernels/gather.py`` says why field-major).  A
   commit/delete/compact bumps the generation token, so stale buffers become
   unreachable and age out by LRU (or are evicted eagerly through the
   ``IncrementalIndexer.subscribe`` mutation hook).
@@ -126,9 +127,10 @@ _ZERO_EXTENT = KeyExtent("", 0, 0, 0, ())
 class _FamilyBuffer:
     """One resident (token, shard, family) upload."""
 
-    buf: jax.Array  # [n_blocks_pow2 * BLOCK, 2] int32 (doc, pos) streams
+    buf: jax.Array  # [2, n_blocks_pow2 * BLOCK] int32 (doc; pos) streams
     extents: dict  # canonical key -> KeyExtent
     nbytes: int
+    used_bytes: int  # live event rows; the rest is block and pow2 padding
 
 
 @dataclass
@@ -218,6 +220,7 @@ class PostingArena:
         self.misses = 0  # family uploads + budget refusals
         self.uploads = 0
         self.upload_bytes = 0  # H2D bytes spent on arena uploads
+        self.upload_sec = 0.0  # host build + H2D wall time of those uploads
         self.evictions = 0
         # §14 fault-injection hook (DESIGN.md §14): when set, acquire
         # rounds fire the "arena.acquire" injection point; injected
@@ -325,6 +328,7 @@ class PostingArena:
     def _upload_family(self, view: IndexSet, fname: str) -> _FamilyBuffer | None:
         from ..index.store import family_rows
 
+        t0 = time.perf_counter()
         width = POSTING_WIDTH[fname]
         mapping = getattr(view, fname)
         keys, arrays, _rows, _starts = family_rows(mapping, width)
@@ -332,6 +336,7 @@ class PostingArena:
         chunks: list[np.ndarray] = []
         extents: dict = {}
         blk = 0
+        used_rows = 0
         for k, a in zip(keys, arrays):
             n = len(a)
             if n == 0:
@@ -343,10 +348,11 @@ class PostingArena:
             for doc, pos in _slot_streams(a, width):
                 ne = len(doc)
                 n_blocks = -(-ne // block)
-                pad = np.full((n_blocks * block, 2), -1, np.int32)
-                pad[:ne, 0] = doc
-                pad[:ne, 1] = pos
+                pad = np.full((2, n_blocks * block), -1, np.int32)
+                pad[0, :ne] = doc
+                pad[1, :ne] = pos
                 chunks.append(pad)
+                used_rows += ne
                 slots.append(
                     SlotExtent(blk, ne, int(pos.max()) if ne else 0)
                 )
@@ -361,14 +367,20 @@ class PostingArena:
         # pow2 total blocks: arena buffer SHAPES bucket, so the serving
         # program's jit cache stays stable across generations (§9.2)
         total_blocks = 1 << max(0, (max(blk, 1) - 1).bit_length())
-        concat = np.full((total_blocks * block, 2), -1, np.int32)
+        concat = np.full((2, total_blocks * block), -1, np.int32)
         if chunks:
-            cat = np.concatenate(chunks)
-            concat[: len(cat)] = cat
-        buf = jnp.asarray(concat)
+            cat = np.concatenate(chunks, axis=1)
+            concat[:, : cat.shape[1]] = cat
+        buf = jnp.asarray(concat).block_until_ready()
         self.uploads += 1
         self.upload_bytes += concat.nbytes
-        return _FamilyBuffer(buf=buf, extents=extents, nbytes=concat.nbytes)
+        self.upload_sec += time.perf_counter() - t0
+        return _FamilyBuffer(
+            buf=buf,
+            extents=extents,
+            nbytes=concat.nbytes,
+            used_bytes=used_rows * concat.itemsize * concat.shape[0],
+        )
 
     # ---- invalidation (generation hooks, DESIGN.md §13.2) ------------------
 
@@ -440,12 +452,14 @@ class PostingArena:
         lookups = self.hits + self.misses
         return {
             "arena_bytes": self._bytes,
+            "arena_used_bytes": sum(fb.used_bytes for fb in self._entries.values()),
             "arena_entries": len(self._entries),
             "arena_hit_rate": self.hits / lookups if lookups else 0.0,
             "arena_hits": self.hits,
             "arena_misses": self.misses,
             "arena_uploads": self.uploads,
             "arena_upload_bytes": self.upload_bytes,
+            "arena_upload_sec": self.upload_sec,
             "arena_evictions": self.evictions,
             "arena_pressure_events": self.pressure_events,
         }
@@ -732,7 +746,6 @@ def _binary_search(a: jax.Array, v: jax.Array, right: bool) -> jax.Array:
         "tier",
         "top_k",
         "use_kernel",
-        "interpret",
     ),
 )
 def arena_serve_batch(
@@ -758,7 +771,6 @@ def arena_serve_batch(
     tier: str,
     top_k: int = 16,
     use_kernel: bool = False,
-    interpret: bool = True,
 ):
     """One device program for an arena-resident query batch (DESIGN.md
     §13.4) — the on-device form of ``extract_segment_events`` +
@@ -795,15 +807,17 @@ def arena_serve_batch(
     window = 2 * max_distance + 1
 
     # ---- stage 0: gather the (doc, pos) event streams ---------------------
+    # Per-event streams are kept 1-D or field-major ([5, E], [L, E]): the
+    # TPU tiles an array's two minor dims by (8, 128), so an event-major
+    # [E, 5] or [E, L] temporary would pad its narrow minor dim to 128 lanes
+    # (25-32x the HBM) and exhaust the chip at corpus scale.
     doc_l, pos_l, seg_l, lem_l, kd_l, em_l, key_l = [], [], [], [], [], [], []
     for fi, _fname in enumerate(families):
         if use_kernel:
             # block-aligned Pallas gather (scalar-prefetched DMA steering)
             f_src, f_nv, meta_b = gather_args[fi]
-            rows = gather_blocks(
-                buffers[fi], f_src, f_nv, block=block, interpret=interpret
-            )
-            meta = jnp.repeat(meta_b, block, axis=0)  # [G*B, 5]
+            doc_f, pos_f = gather_blocks(buffers[fi], f_src, f_nv, block=block)
+            meta = jnp.repeat(meta_b.T, block, axis=1)  # [5, G*B]
         else:
             # dense jnp gather: descriptor extents pack back to back, so the
             # event budget tracks REAL rows (no per-extent block padding)
@@ -813,17 +827,17 @@ def arena_serve_batch(
             desc = jnp.clip(desc, 0, d_dest.shape[0] - 1)
             within = iota - d_dest[desc]
             alive = within < d_n[desc]
-            srcrow = jnp.clip(d_srcrow[desc] + within, 0, buffers[fi].shape[0] - 1)
-            rows = jnp.take(buffers[fi], srcrow, axis=0)
-            rows = jnp.where(alive[:, None], rows, jnp.int32(-1))
-            meta = d_meta[desc]  # [E, 5]
-        doc_l.append(rows[:, 0])
-        pos_l.append(rows[:, 1])
-        seg_l.append(meta[:, 0])
-        lem_l.append(meta[:, 1])
-        kd_l.append(meta[:, 2])
-        em_l.append(meta[:, 3])
-        key_l.append(meta[:, 4])
+            srcrow = jnp.clip(d_srcrow[desc] + within, 0, buffers[fi].shape[1] - 1)
+            doc_f = jnp.where(alive, buffers[fi][0, srcrow], jnp.int32(-1))
+            pos_f = jnp.where(alive, buffers[fi][1, srcrow], jnp.int32(-1))
+            meta = d_meta.T[:, desc]  # [5, E]
+        doc_l.append(doc_f)
+        pos_l.append(pos_f)
+        seg_l.append(meta[0])
+        lem_l.append(meta[1])
+        kd_l.append(meta[2])
+        em_l.append(meta[3])
+        key_l.append(meta[4])
     doc = jnp.concatenate(doc_l)
     pos = jnp.concatenate(pos_l)
     seg = jnp.concatenate(seg_l)
@@ -908,29 +922,58 @@ def arena_serve_batch(
     # per-(row, lemma) group bounds once (small), reused by Step-2 and the
     # per-event cover; `cov` holds deduped events only, so range sizes are
     # exactly the distinct-position counts the host pack bincounts
-    l_iota = jnp.arange(lemma_budget, dtype=jnp.int32)
-    grp_rl = ((r_iota[:, None] << lb) | l_iota[None, :]) << nb  # [R, L]
-    lo_rl = _binary_search(cov, grp_rl, right=False)
-    cnt_rl = _binary_search(cov, grp_rl | (n_budget - 1), right=True) - lo_rl
-    mult_rows = mult[row_seg_c]  # [R, L] (0 = unused slot, trivially passes)
-    ok_row = row_used & jnp.all(cnt_rl >= mult_rows, axis=1)
+    # One loop step per lemma slot over 1-D [R] / [E] streams: a [R, L] or
+    # [E, L] tensor would pad its narrow lemma dim to 128 lanes on the TPU
+    # (stage 0), and XLA lays the transposed [L, E] gathers out the same way.
+    mult_t = mult.T  # [L, S] (0 = unused slot, trivially passes)
+
+    def lemma_rows(l):
+        """Per-row ``(group base key, first index in cov, mult)`` of lemma
+        slot ``l``."""
+        grp = ((r_iota << lb) | l) << nb
+        return grp, _binary_search(cov, grp, right=False), mult_t[l][row_seg_c]
+
+    def row_gate(l, ok):
+        grp, lo, m = lemma_rows(l)
+        cnt = _binary_search(cov, grp | (n_budget - 1), right=True) - lo
+        return ok & (cnt >= m)
+
+    ok_row = jax.lax.fori_loop(0, lemma_budget, row_gate, row_used)
     live = uniq & ok_row[row2]
 
     # event-centric rank cover (§9.3 identity): for event (row, pos) and
     # lemma l, cnt = occurrences of l at or before pos; the fragment start
     # is the mult-th latest, gathered straight from the sorted stream
-    grp_e = ((row2[:, None] << lb) | l_iota[None, :]) << nb  # [E, L]
-    hi_e = _binary_search(cov, grp_e | pos2[:, None], right=True)
-    lo_e = lo_rl[row2]  # [E, L]
-    cnt = hi_e - lo_e
-    mult_e = mult_rows[row2]  # [E, L]
-    active = mult_e > 0
-    have = cnt >= mult_e
-    sel = jnp.clip(lo_e + cnt - mult_e, 0, e - 1)
-    p_sel = cov[sel] & (n_budget - 1)
-    p_sel = jnp.where(active & have, p_sel, n_budget)
-    start = jnp.min(p_sel, axis=-1)
-    covered = jnp.all(have | ~active, axis=-1) & jnp.any(active, axis=-1)
+    def event_cover(l, carry):
+        start, all_have, any_active = carry
+        _grp, lo_r, m_r = lemma_rows(l)
+        grp_e = ((row2 << lb) | l) << nb
+        hi_e = _binary_search(cov, grp_e | pos2, right=True)
+        lo_e = lo_r[row2]
+        cnt = hi_e - lo_e
+        mult_e = m_r[row2]
+        active = mult_e > 0
+        have = cnt >= mult_e
+        sel = jnp.clip(lo_e + cnt - mult_e, 0, e - 1)
+        p_sel = cov[sel] & (n_budget - 1)
+        p_sel = jnp.where(active & have, p_sel, n_budget)
+        return (
+            jnp.minimum(start, p_sel),
+            all_have & (have | ~active),
+            any_active | active,
+        )
+
+    start, all_have, any_active = jax.lax.fori_loop(
+        0,
+        lemma_budget,
+        event_cover,
+        (
+            jnp.full((e,), n_budget, jnp.int32),
+            jnp.ones((e,), jnp.bool_),
+            jnp.zeros((e,), jnp.bool_),
+        ),
+    )
+    covered = all_have & any_active
     emit = live & covered & (start < n_budget) & (pos2 - start < window)
     start = jnp.where(emit, start, pos2)
 
@@ -1042,7 +1085,6 @@ def _static_kwargs(
     max_distance: int,
     top_k: int,
     use_kernel: bool,
-    interpret: bool,
 ) -> dict:
     """Static (jit-cache-keyed) kwargs of :func:`arena_serve_batch` for a
     plan — the shape/config half of the program's signature."""
@@ -1061,7 +1103,6 @@ def _static_kwargs(
         tier=plan.tier,
         top_k=top_k,
         use_kernel=use_kernel,
-        interpret=interpret,
     )
 
 
@@ -1071,7 +1112,6 @@ def lower_arena_batch(
     max_distance: int,
     top_k: int = 16,
     use_kernel: bool = False,
-    interpret: bool = True,
 ):
     """Lower ONE arena device program WITHOUT dispatching it (DESIGN.md
     §15.4).  Returns the jax ``Lowered`` object; callers compile it and feed
@@ -1085,7 +1125,6 @@ def lower_arena_batch(
             max_distance=max_distance,
             top_k=top_k,
             use_kernel=use_kernel,
-            interpret=interpret,
         ),
     )
 
@@ -1096,7 +1135,6 @@ def run_arena_batch(
     max_distance: int,
     top_k: int = 16,
     use_kernel: bool = False,
-    interpret: bool = True,
     stats: QueryStats | None = None,
     phases: dict | None = None,
     readout: str = "device",
@@ -1136,7 +1174,6 @@ def run_arena_batch(
             max_distance=max_distance,
             top_k=top_k,
             use_kernel=use_kernel,
-            interpret=interpret,
         ),
     )
     if stats is not None:

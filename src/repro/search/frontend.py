@@ -616,7 +616,6 @@ class ServingFrontend:
                 top_k=top_k,
                 compute_dtype=self.compute_dtype,
                 use_kernel=self.use_kernel,
-                interpret=True,
             )
             jax_.block_until_ready(out)
             programs += 1

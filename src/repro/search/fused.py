@@ -587,7 +587,7 @@ def fused_serve_batch(
     top_k: int = 16,
     compute_dtype: str = "uint8",  # §Perf-3: dense-path occupancy fits u8
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One device program for a whole query batch.
 
@@ -607,6 +607,9 @@ def fused_serve_batch(
     device: ``res`` is the §15.1 dense result buffer
     (``_assemble_fragments`` — sorted unique ``(q, doc, start, end)`` rows
     plus per-query counts), read out as one fixed-shape D2H copy.
+
+    ``interpret`` reaches the Pallas kernel only (``use_kernel=True``);
+    ``None`` is the backend check of ``kernels/backend.py``.
     """
     r, l, k = postab.shape
     n = window_len
@@ -870,7 +873,6 @@ def lower_query_batch(
     top_k: int = 16,
     use_kernel: bool = False,
     compute_dtype: str = "uint8",
-    interpret: bool = True,
 ):
     """Lower ONE fused device program WITHOUT dispatching it (DESIGN.md
     §15.4).  Returns the jax ``Lowered`` object for the exact program
@@ -890,7 +892,6 @@ def lower_query_batch(
         top_k=top_k,
         compute_dtype=compute_dtype,
         use_kernel=use_kernel,
-        interpret=interpret,
     )
 
 
@@ -901,7 +902,6 @@ def run_query_batch(
     top_k: int = 16,
     use_kernel: bool = False,
     compute_dtype: str = "uint8",
-    interpret: bool = True,
     stats: QueryStats | None = None,
     readout: str = "device",
     defer: bool = False,
@@ -945,7 +945,6 @@ def run_query_batch(
         top_k=top_k,
         compute_dtype=compute_dtype,
         use_kernel=use_kernel,
-        interpret=interpret,
     )
     _DISPATCHES += 1
     if stats is not None:
@@ -1077,7 +1076,6 @@ def serve_query_batch(
     doc_len: int = 512,
     use_kernel: bool = False,
     compute_dtype: str = "uint8",
-    interpret: bool = True,
     stats: QueryStats | Sequence[QueryStats] | None = None,
     batch_stats: QueryStats | None = None,
     residencies: dict | None = None,
@@ -1208,7 +1206,6 @@ def serve_query_batch(
                     max_distance=max_distance,
                     top_k=top_k,
                     use_kernel=use_kernel,
-                    interpret=interpret,
                     stats=batch_stats,
                     phases=sink,
                     readout=readout,
@@ -1231,7 +1228,6 @@ def serve_query_batch(
                     top_k=top_k,
                     use_kernel=use_kernel,
                     compute_dtype=compute_dtype,
-                    interpret=interpret,
                     stats=batch_stats,
                     readout=readout,
                     defer=defer,

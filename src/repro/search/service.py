@@ -46,6 +46,7 @@ import json
 import socket
 import socketserver
 import threading
+import traceback
 from collections import deque
 from typing import Sequence
 
@@ -92,6 +93,7 @@ class Ticket:
         "latency_sec",
         "_event",
         "_response",
+        "_error",
     )
 
     def __init__(self, request: SearchRequest, seq: int, enqueued_at: float):
@@ -106,22 +108,32 @@ class Ticket:
         self.latency_sec = 0.0
         self._event = threading.Event()
         self._response: QueryResponse | None = None
+        self._error: BaseException | None = None
 
     def done(self) -> bool:
-        """True once the response is set (§16.1) — never un-sets."""
+        """True once the response (or the failure) is set (§16.1) — never
+        un-sets."""
         return self._event.is_set()
 
     def result(self, timeout: float | None = None) -> QueryResponse:
         """Block until the daemon completes this ticket and return the
         response (§16.1).  Idempotent; raises ``TimeoutError`` only when a
         real ``timeout`` expires (virtual-clock runs complete tickets
-        synchronously inside ``pump``/``replay``, so tests never wait)."""
+        synchronously inside ``pump``/``replay``, so tests never wait), and
+        re-raises the exception that failed the daemon's scheduler step if
+        this ticket was queued or in flight when it was raised."""
         if not self._event.wait(timeout):
             raise TimeoutError(f"ticket {self.seq} not completed in {timeout}s")
+        if self._error is not None:
+            raise self._error
         return self._response
 
     def _complete(self, response: QueryResponse) -> None:
         self._response = response
+        self._event.set()
+
+    def _fail(self, error: BaseException) -> None:
+        self._error = error
         self._event.set()
 
 
@@ -164,6 +176,12 @@ class ServiceDaemon:
     response flagged ``stats.shed`` / ``stats.partial`` that never
     reaches a frontend and is never cached.
 
+    Failure: an exception raised inside :meth:`pump` (a frontend or device
+    program error) completes every queued and in-flight ticket with that
+    exception — ``Ticket.result`` re-raises it — and so does every later
+    ``submit``; the daemon thread then exits.  A failed program fails its
+    requests instead of leaving their callers waiting.
+
     Deterministic mode (§16.4): give every replica AND the daemon one
     shared virtual clock and drive the scheduler with :meth:`pump` /
     :meth:`drain` / :meth:`replay` — no threads, no sleeps, exact tick
@@ -204,11 +222,13 @@ class ServiceDaemon:
         self._rr = 0  # round-robin replica cursor
         self._thread: threading.Thread | None = None
         self._stopping = False
+        self._failure: BaseException | None = None
 
         self._seq = 0
         self._submitted = 0
         self._completed = 0
         self._shed_queue = 0
+        self._failed = 0
         self._batches = 0
         self._batched = 0
         self._queue_peak = 0
@@ -243,6 +263,10 @@ class ServiceDaemon:
             ticket = Ticket(req, self._seq, self.clock.now())
             self._seq += 1
             self._submitted += 1
+            if self._failure is not None:
+                self._failed += 1
+                ticket._fail(self._failure)
+                return ticket
             if self._stopping or len(self._queue) >= self.max_queue:
                 self._shed_queue += 1
                 ticket.shed_at_queue = True
@@ -304,7 +328,11 @@ class ServiceDaemon:
                         deadline_sec=eff,
                     )
                 )
-            finalize = replica.submit_many(slate)
+            try:
+                finalize = replica.submit_many(slate)
+            except Exception as exc:
+                self._fail_batch(idx, tickets, exc)
+                raise
             with self._lock:
                 self._inflight.append(_Inflight(idx, tickets, finalize, now))
                 self._batches += 1
@@ -320,7 +348,11 @@ class ServiceDaemon:
             inf = self._inflight.popleft()
         # the blocking device readout runs OUTSIDE the lock: this is the
         # window in which submit() keeps admitting — continuous batching
-        responses = inf.finalize()
+        try:
+            responses = inf.finalize()
+        except Exception as exc:
+            self._fail_batch(inf.replica, inf.tickets, exc)
+            raise
         now = self.clock.now()
         with self._work:
             for ticket, resp in zip(inf.tickets, responses):
@@ -331,6 +363,31 @@ class ServiceDaemon:
             self._work.notify_all()
         return True
 
+    def _fail_batch(self, replica: int, tickets: list[Ticket], exc: BaseException) -> None:
+        """Fail the tickets of the batch whose submit or readout raised:
+        they are in neither the queue nor the in-flight list any more."""
+        with self._work:
+            for t in tickets:
+                t._fail(exc)
+            self._failed += len(tickets)
+            self._busy[replica] = False
+            self._work.notify_all()
+
+    def _fail_pending(self, exc: BaseException) -> None:
+        """Record the failure and complete every queued and in-flight ticket
+        with it; later submits fail the same way."""
+        with self._work:
+            self._failure = exc
+            pending = list(self._queue)
+            pending += [t for b in self._inflight for t in b.tickets]
+            self._queue.clear()
+            self._inflight.clear()
+            self._busy = [False] * len(self.replicas)
+            for t in pending:
+                t._fail(exc)
+            self._failed += len(pending)
+            self._work.notify_all()
+
     def pump(self) -> bool:
         """One deterministic scheduler step (§16.2): launch batches onto
         every idle replica, then retire the oldest in-flight batch
@@ -338,9 +395,14 @@ class ServiceDaemon:
         the ONLY scheduling logic — the daemon thread, :meth:`drain` and
         :meth:`replay` all run exactly this step, so threaded and
         virtual-clock runs make identical batching decisions for identical
-        queue states."""
-        launched = self._launch_ready()
-        retired = self._retire_oldest()
+        queue states.  An exception fails every pending ticket (class
+        docstring) and propagates to the caller."""
+        try:
+            launched = self._launch_ready()
+            retired = self._retire_oldest()
+        except Exception as exc:
+            self._fail_pending(exc)
+            raise
         return launched or retired
 
     def drain(self) -> None:
@@ -422,7 +484,13 @@ class ServiceDaemon:
                     self._work.wait(timeout=self.poll_interval_s)
                 if self._stopping and not self._queue and not self._inflight:
                     return
-            self.pump()
+            try:
+                self.pump()
+            except Exception:
+                # every pending ticket already carries the exception; the
+                # thread ends here and later submits fail immediately
+                traceback.print_exc()
+                return
 
     def stop(self, drain: bool = True) -> None:
         """Stop serving (§16.3).  New submits shed immediately from this
@@ -456,9 +524,9 @@ class ServiceDaemon:
         and the exact batch-occupancy histogram — ``mean_batch_occupancy``
         > 1 is the pinned evidence that batches formed from arrivals
         admitted while earlier batches were in flight (continuous
-        batching), and ``submitted == completed + shed_queue + queued +
-        inflight`` is the no-lost-ticket conservation the property tests
-        assert."""
+        batching), and ``submitted == completed + shed_queue + failed +
+        queued + inflight`` is the no-lost-ticket conservation the property
+        tests assert."""
         with self._lock:
             inflight_reqs = sum(len(b.tickets) for b in self._inflight)
             batches = self._batches
@@ -469,6 +537,7 @@ class ServiceDaemon:
                 "submitted": self._submitted,
                 "completed": self._completed,
                 "shed_queue": self._shed_queue,
+                "failed": self._failed,
                 "queued": len(self._queue),
                 "inflight_requests": inflight_reqs,
                 "queue_peak": self._queue_peak,
@@ -499,7 +568,15 @@ class RequestHandle:
     request is never answered twice.
     """
 
-    __slots__ = ("request_id", "request", "ticket", "readmissions", "_event", "_response")
+    __slots__ = (
+        "request_id",
+        "request",
+        "ticket",
+        "readmissions",
+        "_event",
+        "_response",
+        "_error",
+    )
 
     def __init__(self, request_id: str, request: SearchRequest):
         self.request_id = request_id
@@ -508,6 +585,7 @@ class RequestHandle:
         self.readmissions = 0
         self._event = threading.Event()
         self._response: QueryResponse | None = None
+        self._error: BaseException | None = None
 
     def done(self) -> bool:
         """True once the one-and-only response is recorded (§18.3)."""
@@ -516,13 +594,17 @@ class RequestHandle:
     def result(self, timeout: float | None = None) -> QueryResponse:
         """Block until the response is recorded and return it — the same
         object on every call, across client retries and primary failovers
-        (§18.3 idempotency).  Raises ``TimeoutError`` on a real expiry."""
+        (§18.3 idempotency).  Raises ``TimeoutError`` on a real expiry, and
+        re-raises the exception of a failed ticket (``Ticket.result``)."""
         if not self._event.wait(timeout):
             raise TimeoutError(f"request {self.request_id!r} not completed in {timeout}s")
+        if self._error is not None:
+            raise self._error
         return self._response
 
-    def _record(self, response: QueryResponse) -> None:
+    def _record(self, response: QueryResponse | None, error: BaseException | None = None) -> None:
         self._response = response
+        self._error = error
         self._event.set()
 
 
@@ -727,7 +809,7 @@ class ReplicatedServiceDaemon:
 
     def _record(self, handle: RequestHandle, ticket: Ticket) -> None:
         if handle.ticket is ticket and not handle.done():
-            handle._record(ticket._response)
+            handle._record(ticket._response, ticket._error)
 
     def _propagate(self) -> None:
         for handle in self._registry.values():
@@ -746,9 +828,11 @@ class ReplicatedServiceDaemon:
             self._maybe_fire_crash()
             self._maybe_failover()
             p = self._primary if self.alive[self._primary] else None
-        worked = self.daemons[p].pump() if p is not None else False
-        with self._lock:
-            self._propagate()
+        try:
+            worked = self.daemons[p].pump() if p is not None else False
+        finally:
+            with self._lock:
+                self._propagate()
         return worked
 
     def drain(self) -> None:

@@ -106,8 +106,7 @@ def serve_step_sharded(
     per-shard top-k lists (KBs).  This is exactly DESIGN.md §4's
     document-parallel layout — B stays replicated, clusters are the grid.
     """
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = get_mesh() if get_mesh is not None else None
+    mesh = jax.sharding.get_abstract_mesh()
     ns, b = postings.shape[0], postings.shape[1]
     kk = min(top_k, n_clusters)
 
@@ -123,7 +122,7 @@ def serve_step_sharded(
             out["n_fragments"][None],
         )
 
-    if mesh is not None and mesh.axis_names:
+    if mesh.axis_names:
         axes = tuple(mesh.axis_names)
         inner = jax.shard_map(
             local,

@@ -48,7 +48,8 @@ def _work(queries, idx, lemmatizer):
 
 def test_gather_blocks_kernel_equals_ref():
     rng = np.random.default_rng(0)
-    arena = jnp.asarray(rng.integers(0, 1000, (8 * ARENA_BLOCK, 2)).astype(np.int32))
+    # field-major arena buffer: [2, rows] (doc row over pos row)
+    arena = jnp.asarray(rng.integers(0, 1000, (2, 8 * ARENA_BLOCK)).astype(np.int32))
     src = jnp.asarray(np.array([3, 0, 7, 7], np.int32))
     nv = jnp.asarray(np.array([ARENA_BLOCK, 5, 0, 128], np.int32))
     k = np.asarray(gather_blocks(arena, src, nv))
@@ -56,10 +57,13 @@ def test_gather_blocks_kernel_equals_ref():
     np.testing.assert_array_equal(k, r)
     # masking: rows past n_valid are the -1 sentinel, live rows are copies
     np.testing.assert_array_equal(
-        k[: ARENA_BLOCK], np.asarray(arena)[3 * ARENA_BLOCK : 4 * ARENA_BLOCK]
+        k[:, :ARENA_BLOCK], np.asarray(arena)[:, 3 * ARENA_BLOCK : 4 * ARENA_BLOCK]
     )
-    assert (k[ARENA_BLOCK + 5 : 2 * ARENA_BLOCK] == -1).all()
-    assert (k[2 * ARENA_BLOCK : 3 * ARENA_BLOCK] == -1).all()
+    np.testing.assert_array_equal(
+        k[:, ARENA_BLOCK : ARENA_BLOCK + 5], np.asarray(arena)[:, :5]
+    )
+    assert (k[:, ARENA_BLOCK + 5 : 2 * ARENA_BLOCK] == -1).all()
+    assert (k[:, 2 * ARENA_BLOCK : 3 * ARENA_BLOCK] == -1).all()
 
 
 # ---------------------------------------------------------------------------

@@ -150,8 +150,8 @@ def test_tight_clusters_always_found(query_ids, seed):
 @given(st.integers(0, 10_000))
 def test_random_corpus_se24_equals_oracle(seed):
     """On arbitrary Zipf corpora SE2.4 must equal its oracle (the Step-2
-    gate may only skip fragments no key posting covers — which the oracle,
-    built from the same postings, also cannot see)."""
+    gate may only skip records that share no reportable fragment with the
+    other keys' records)."""
     rng = np.random.default_rng(seed)
     vocab = [f"w{i}" for i in range(15)]
     probs = np.array([1 / (i + 1) ** 1.1 for i in range(15)])
@@ -194,3 +194,26 @@ def test_se24_multi_lemma_position_counts_both_lemmas():
     assert sorted(got) == expected
     frags = {(r.doc_id, r.start, r.end) for r in got}
     assert (0, 3, 6) in frags, "the multi-lemma-position minimal fragment"
+
+
+def test_se24_step2_gate_covers_key_reach():
+    """Regression: Step 2 compares the keys' anchor positions, while a key
+    record's components reach MaxDistance either side of its anchor.  Here
+    (D=4) the fragment w1(32) w4(33) w5(34) w3(40) has span 2D; its events
+    come from a (w1, w4, w5) record anchored at 32 and a (w3, w4*, w5*)
+    record anchored at 40.  A gate of ``delta < 2D`` skipped both records
+    and lost the fragment the oracle and the device engines report."""
+    rng = np.random.default_rng(5575)
+    vocab = [f"w{i}" for i in range(15)]
+    probs = np.array([1 / (i + 1) ** 1.1 for i in range(15)])
+    probs /= probs.sum()
+    texts = [" ".join(rng.choice(vocab, size=60, p=probs)) for _ in range(8)]
+    idx = build_indexes(
+        DocumentStore.from_texts(texts), sw_count=10_000, fu_count=0, max_distance=4
+    )
+    sub = Subquery(("w3", "w4", "w1", "w5"))
+    keys = select_keys(sub, idx.fl)
+    post = {k: idx.key_postings(k.components) for k in keys}
+    got, _ = se24_combiner(sub, idx)
+    assert sorted(got) == sorted(oracle_search(sub, keys, post, idx.max_distance))
+    assert (2, 32, 40) in {(r.doc_id, r.start, r.end) for r in got}

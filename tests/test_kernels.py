@@ -26,7 +26,8 @@ def test_proximity_kernel_sweep(b, l, n, max_distance, dtype):
     active = rng.integers(1, l + 1)
     mult[:, :active] = rng.integers(1, 3, (b, active))
     emit_k, start_k = proximity_window(
-        jnp.asarray(occ.astype(np.int32)), jnp.asarray(mult), max_distance
+        jnp.asarray(occ), jnp.asarray(mult), max_distance,
+        compute_dtype=np.dtype(dtype).name,
     )
     emit_r, start_r = proximity_window_ref(
         jnp.asarray(occ.astype(np.int32)), jnp.asarray(mult), max_distance

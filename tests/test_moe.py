@@ -6,7 +6,6 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -60,10 +59,6 @@ print("LOCAL_OK", err)
 """
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="subprocess uses jax.set_mesh (jax >= 0.6); not available here",
-)
 def test_local_dispatch_multidevice_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROC],

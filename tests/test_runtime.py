@@ -152,10 +152,6 @@ def test_adamw_converges_on_quadratic():
     assert float(loss(params)) < 1e-3
 
 
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="subprocess uses jax.shard_map (jax >= 0.6); not available here",
-)
 def test_grad_compression_error_feedback_subprocess():
     """int8 compressed psum with error feedback: mean of shard gradients is
     recovered to within quantization noise, and residuals carry over."""

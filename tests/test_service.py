@@ -272,3 +272,60 @@ def test_tcp_deadline_and_bad_requests(small_index, lemmatizer):
         server.shutdown()
         server.server_close()
         daemon.stop()
+
+
+# ---------------------------------------------------------------------------
+# a failed device program fails its requests instead of hanging them
+# ---------------------------------------------------------------------------
+
+
+class _FailingFrontend:
+    """A replica whose batch submit, or its blocking readout, raises — what
+    a device program that fails to compile or run looks like to the
+    daemon."""
+
+    max_batch = 2
+
+    def __init__(self, where: str):
+        self.where = where
+
+    def submit_many(self, requests):
+        if self.where == "submit":
+            raise RuntimeError("device program failed")
+
+        def finalize():
+            raise RuntimeError("device program failed")
+
+        return finalize
+
+
+@pytest.mark.parametrize("where", ["submit", "readout"])
+@pytest.mark.parametrize("mode", ["drain", "thread"])
+def test_daemon_failure_fails_every_pending_ticket(where, mode):
+    """An exception inside ``pump`` completes every queued and in-flight
+    ticket with it (``result`` re-raises at once), later submits fail the
+    same way, and the conservation identity still holds."""
+    clock = ManualClock()
+    daemon = ServiceDaemon([_FailingFrontend(where)], clock=clock, max_queue=16)
+    tickets = [daemon.submit(f"q{i}") for i in range(5)]
+    if mode == "drain":
+        with pytest.raises(RuntimeError, match="device program failed"):
+            daemon.drain()
+    else:
+        daemon.start()
+    for t in tickets:
+        # a real timeout guards the threaded mode only: the failure is set
+        # by the daemon thread as soon as the first batch raises
+        with pytest.raises(RuntimeError, match="device program failed"):
+            t.result(timeout=30.0)
+        assert t.done()
+    late = daemon.submit("after the failure")
+    with pytest.raises(RuntimeError, match="device program failed"):
+        late.result(timeout=0)
+    daemon.stop()
+    m = daemon.metrics()
+    assert m["failed"] == 6 and m["completed"] == 0
+    assert m["submitted"] == (
+        m["completed"] + m["shed_queue"] + m["failed"] + m["queued"]
+        + m["inflight_requests"]
+    )
