@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from ..core.postings import QueryStats, SearchResult
 from ..index.builder import POSTING_WIDTH, IndexSet
 from ..kernels.gather import ARENA_BLOCK, gather_blocks, gather_blocks_ref
+from ..runtime.spans import span
 from .fused import _assemble_fragments, bucket_pow2 as _bucket
 
 __all__ = [
@@ -1136,7 +1137,6 @@ def run_arena_batch(
     top_k: int = 16,
     use_kernel: bool = False,
     stats: QueryStats | None = None,
-    phases: dict | None = None,
     readout: str = "device",
     defer: bool = False,
 ):
@@ -1157,87 +1157,72 @@ def run_arena_batch(
 
     if readout not in ("device", "host"):
         raise ValueError(f"unknown readout mode: {readout!r}")
-    t0 = time.perf_counter()
-    args, h2d = _device_args(plan, use_kernel)
-    if stats is not None:
-        stats.h2d_bytes += h2d
     # enqueue time only — the premature block_until_ready(args[1:]) that
     # used to sit here forced a full descriptor H2D sync into the dispatch
     # window (the fused path's twin of the same bug)
-    if phases is not None:
-        phases.setdefault("h2d_us", []).append((time.perf_counter() - t0) * 1e6)
-        t0 = time.perf_counter()
-    out = arena_serve_batch(
-        *args,
-        **_static_kwargs(
-            plan,
-            max_distance=max_distance,
-            top_k=top_k,
-            use_kernel=use_kernel,
-        ),
-    )
+    with span("serve.h2d"):
+        args, h2d = _device_args(plan, use_kernel)
+    if stats is not None:
+        stats.h2d_bytes += h2d
+    with span("serve.dispatch"):
+        out = arena_serve_batch(
+            *args,
+            **_static_kwargs(
+                plan,
+                max_distance=max_distance,
+                top_k=top_k,
+                use_kernel=use_kernel,
+            ),
+        )
     if stats is not None:
         stats.device_dispatches += 1
-    if phases is not None:
-        phases.setdefault("dispatch_us", []).append((time.perf_counter() - t0) * 1e6)
 
     nq = plan.n_queries
 
     def finalize():
-        t1 = time.perf_counter()
-        if phases is not None:
-            # bench-only barrier: device time goes to compute_us, not to
-            # whichever phase bracket encloses the first fetch
+        with span("serve.device_wait"):
             jax.block_until_ready(out)
-            now = time.perf_counter()
-            phases.setdefault("compute_us", []).append((now - t1) * 1e6)
-            t2 = now
-        else:
-            t2 = t1
-        if readout == "device":
-            buf = np.asarray(out["res"])
-            frag_rows, frag_offsets = _split_result_buffer(
-                buf, nq, plan.query_budget
-            )
-            result = FusedBatchResult(
-                frag_rows=frag_rows,
-                frag_offsets=frag_offsets,
-                top_docs=np.asarray(out["top_docs"])[:nq],
-                top_scores=np.asarray(out["top_scores"])[:nq],
-                n_fragments=np.asarray(out["n_fragments"])[:nq],
-            )
-        else:
-            nb = (plan.n_budget - 1).bit_length()
-            lb = max((plan.lemma_budget - 1).bit_length(), 1)
-            emit = np.asarray(out["emit"])
-            (hits,) = np.nonzero(emit)
-            comp = np.asarray(out["comp"])[hits].astype(np.int64)
-            starts = np.asarray(out["start"])[hits].astype(np.int64)
-            ends = (comp >> lb) & (plan.n_budget - 1)
-            rows = comp >> (lb + nb)
-            row_doc = np.asarray(out["row_doc"]).astype(np.int64)
-            row_query = np.asarray(out["row_query"]).astype(np.int64)
-            docs = row_doc[rows]
-            q_of = row_query[rows]
-            live = (q_of >= 0) & (q_of < nq)
-            u_q, u_doc, u_start, u_end = _dedup_fragments(
-                q_of[live], docs[live], starts[live], ends[live]
-            )
-            per_query: list[list[SearchResult]] = [[] for _ in range(nq)]
-            for qi, d, st, en in zip(
-                u_q.tolist(), u_doc.tolist(), u_start.tolist(), u_end.tolist()
-            ):
-                per_query[qi].append(SearchResult(doc_id=d, start=st, end=en))
-            result = FusedBatchResult(
-                per_query=per_query,
-                top_docs=np.asarray(out["top_docs"])[:nq],
-                top_scores=np.asarray(out["top_scores"])[:nq],
-                n_fragments=np.asarray(out["n_fragments"])[:nq],
-            )
-        if phases is not None:
-            phases.setdefault("readout_us", []).append(
-                (time.perf_counter() - t2) * 1e6
-            )
+        with span("serve.readout"):
+            if readout == "device":
+                buf = np.asarray(out["res"])
+                frag_rows, frag_offsets = _split_result_buffer(
+                    buf, nq, plan.query_budget
+                )
+                result = FusedBatchResult(
+                    frag_rows=frag_rows,
+                    frag_offsets=frag_offsets,
+                    top_docs=np.asarray(out["top_docs"])[:nq],
+                    top_scores=np.asarray(out["top_scores"])[:nq],
+                    n_fragments=np.asarray(out["n_fragments"])[:nq],
+                )
+            else:
+                nb = (plan.n_budget - 1).bit_length()
+                lb = max((plan.lemma_budget - 1).bit_length(), 1)
+                emit = np.asarray(out["emit"])
+                (hits,) = np.nonzero(emit)
+                comp = np.asarray(out["comp"])[hits].astype(np.int64)
+                starts = np.asarray(out["start"])[hits].astype(np.int64)
+                ends = (comp >> lb) & (plan.n_budget - 1)
+                rows = comp >> (lb + nb)
+                row_doc = np.asarray(out["row_doc"]).astype(np.int64)
+                row_query = np.asarray(out["row_query"]).astype(np.int64)
+                docs = row_doc[rows]
+                q_of = row_query[rows]
+                live = (q_of >= 0) & (q_of < nq)
+                u_q, u_doc, u_start, u_end = _dedup_fragments(
+                    q_of[live], docs[live], starts[live], ends[live]
+                )
+                per_query: list[list[SearchResult]] = [[] for _ in range(nq)]
+                for qi, d, st, en in zip(
+                    u_q.tolist(), u_doc.tolist(), u_start.tolist(), u_end.tolist()
+                ):
+                    per_query[qi].append(SearchResult(doc_id=d, start=st, end=en))
+                result = FusedBatchResult(
+                    per_query=per_query,
+                    top_docs=np.asarray(out["top_docs"])[:nq],
+                    top_scores=np.asarray(out["top_scores"])[:nq],
+                    n_fragments=np.asarray(out["n_fragments"])[:nq],
+                )
         return result
 
     if defer:

@@ -61,6 +61,7 @@ from ..core.postings import QueryStats
 from ..index.builder import IndexSet
 from ..index.incremental import generation_token
 from ..runtime.clock import SystemClock
+from ..runtime.spans import span
 from .planner import QueryPlan, QueryPlanner, SubqueryPlan, execute_plans, resolve_index_views
 
 __all__ = ["SearchRequest", "ServingFrontend", "PostingCache"]
@@ -333,93 +334,96 @@ class ServingFrontend:
             r if isinstance(r, SearchRequest) else SearchRequest(query=r)
             for r in requests
         ]
-        # §14 probe barrier FIRST: recovery replaces shard indexers, so the
-        # generation token and views must resolve after it (a recovered
-        # shard's fresh restore epoch is what strands pre-crash cache keys)
-        supervisor = getattr(self._source, "supervisor", None)
-        rstats = None
-        live_shard_ids: list[int] | None = None
-        if supervisor is not None:
-            rstats = QueryStats()
-            live_shard_ids = supervisor.probe_live_shards(rstats)
-        token = generation_token(self._source)
-        views, _, max_distance, _ = resolve_index_views(self._source)
-        shard_ids = list(range(len(views)))
-        if live_shard_ids is not None and len(live_shard_ids) < len(views):
-            shard_ids = list(live_shard_ids)
-            views = [views[i] for i in shard_ids]
-        # posting-cache keys carry the TRUE shard id (not the position in
-        # the degraded live list), so a degraded slate can never reuse a
-        # slice cached for a different shard under the same token
-        cached_views = [
-            _CachedView(v, self.posting_cache, (token, shard_ids[i]))
-            for i, v in enumerate(views)
-        ]
+        with span("frontend.plan"):
+            # §14 probe barrier FIRST: recovery replaces shard indexers, so the
+            # generation token and views must resolve after it (a recovered
+            # shard's fresh restore epoch is what strands pre-crash cache keys)
+            supervisor = getattr(self._source, "supervisor", None)
+            rstats = None
+            live_shard_ids: list[int] | None = None
+            if supervisor is not None:
+                rstats = QueryStats()
+                live_shard_ids = supervisor.probe_live_shards(rstats)
+            token = generation_token(self._source)
+            views, _, max_distance, _ = resolve_index_views(self._source)
+            shard_ids = list(range(len(views)))
+            if live_shard_ids is not None and len(live_shard_ids) < len(views):
+                shard_ids = list(live_shard_ids)
+                views = [views[i] for i in shard_ids]
+            # posting-cache keys carry the TRUE shard id (not the position in
+            # the degraded live list), so a degraded slate can never reuse a
+            # slice cached for a different shard under the same token
+            cached_views = [
+                _CachedView(v, self.posting_cache, (token, shard_ids[i]))
+                for i, v in enumerate(views)
+            ]
 
-        responses: list = [None] * len(reqs)
-        miss_idx: list[int] = []
-        miss_plans: list[QueryPlan] = []
-        miss_admitted: list[list[SubqueryPlan]] = []
-        miss_budget: list[float] = []
-        miss_shed: list[bool] = []
-        pending: dict[tuple, int] = {}  # (query, top_k) -> first miss index
-        aliases: list[tuple[int, int]] = []  # (dup index, first index)
-        for i, req in enumerate(reqs):
-            ck = (token, req.query, req.top_k, self.use_kernel)
-            hit = self._result_cache.get(ck)
-            if hit is not None:
-                self._result_cache.move_to_end(ck)
-                self._result_hits += 1
-                responses[i] = self._from_cache(hit)
-                continue
-            budget = (
-                req.deadline_sec
-                if req.deadline_sec is not None
-                else self.default_deadline_sec
+            responses: list = [None] * len(reqs)
+            miss_idx: list[int] = []
+            miss_plans: list[QueryPlan] = []
+            miss_admitted: list[list[SubqueryPlan]] = []
+            miss_budget: list[float] = []
+            miss_shed: list[bool] = []
+            pending: dict[tuple, int] = {}  # (query, top_k) -> first miss index
+            aliases: list[tuple[int, int]] = []  # (dup index, first index)
+            for i, req in enumerate(reqs):
+                ck = (token, req.query, req.top_k, self.use_kernel)
+                hit = self._result_cache.get(ck)
+                if hit is not None:
+                    self._result_cache.move_to_end(ck)
+                    self._result_hits += 1
+                    responses[i] = self._from_cache(hit)
+                    continue
+                budget = (
+                    req.deadline_sec
+                    if req.deadline_sec is not None
+                    else self.default_deadline_sec
+                )
+                # coalesce duplicate no-deadline misses: plan + execute once,
+                # fan the single response out (deadlined requests keep their own
+                # admission, so they are never coalesced)
+                dk = (req.query, req.top_k)
+                if budget is None and dk in pending:
+                    aliases.append((i, pending[dk]))
+                    continue
+                self._result_misses += 1
+                p_hits0 = self.posting_cache.hits
+                with span("planner.plan", slot=i):
+                    plan = self.planner.plan(req.query, views=cached_views, generation=token)
+                p_hits = self.posting_cache.hits - p_hits0
+                admitted, _skipped = self._admit(plan, budget)
+                if budget is None:
+                    pending[dk] = i
+                miss_idx.append(i)
+                miss_plans.append(plan)
+                miss_admitted.append(admitted)
+                miss_budget.append(0.0 if budget is None else float(budget))
+                miss_shed.append(False)
+                # stash plan-time accounting to merge into the response stats
+                plan._posting_cache_hits = p_hits  # type: ignore[attr-defined]
+
+            # admission-control load shedding (DESIGN.md §14): misses beyond
+            # max_inflight re-admit under the shed budget — they degrade to
+            # flagged, exactly-ranked partial responses instead of erroring or
+            # queueing unboundedly (request order decides who sheds:
+            # deterministic, and earlier requests are older)
+            if self.max_inflight is not None and len(miss_idx) > self.max_inflight:
+                for j in range(self.max_inflight, len(miss_idx)):
+                    admitted, _ = self._admit(miss_plans[j], self.shed_deadline_sec)
+                    miss_admitted[j] = admitted
+                    miss_budget[j] = self.shed_deadline_sec
+                    miss_shed[j] = True
+                    self._sheds += 1
+
+            # arena residencies are acquired only when something will actually
+            # execute: a fully cache-served slate must never pay acquire work
+            # (a cold acquire re-uploads whole families)
+            residencies = (
+                self._acquire_residencies(views, cached_views, token, shard_ids)
+                if miss_idx
+                else None
             )
-            # coalesce duplicate no-deadline misses: plan + execute once,
-            # fan the single response out (deadlined requests keep their own
-            # admission, so they are never coalesced)
-            dk = (req.query, req.top_k)
-            if budget is None and dk in pending:
-                aliases.append((i, pending[dk]))
-                continue
-            self._result_misses += 1
-            p_hits0 = self.posting_cache.hits
-            plan = self.planner.plan(req.query, views=cached_views, generation=token)
-            p_hits = self.posting_cache.hits - p_hits0
-            admitted, _skipped = self._admit(plan, budget)
-            if budget is None:
-                pending[dk] = i
-            miss_idx.append(i)
-            miss_plans.append(plan)
-            miss_admitted.append(admitted)
-            miss_budget.append(0.0 if budget is None else float(budget))
-            miss_shed.append(False)
-            # stash plan-time accounting to merge into the response stats
-            plan._posting_cache_hits = p_hits  # type: ignore[attr-defined]
 
-        # admission-control load shedding (DESIGN.md §14): misses beyond
-        # max_inflight re-admit under the shed budget — they degrade to
-        # flagged, exactly-ranked partial responses instead of erroring or
-        # queueing unboundedly (request order decides who sheds:
-        # deterministic, and earlier requests are older)
-        if self.max_inflight is not None and len(miss_idx) > self.max_inflight:
-            for j in range(self.max_inflight, len(miss_idx)):
-                admitted, _ = self._admit(miss_plans[j], self.shed_deadline_sec)
-                miss_admitted[j] = admitted
-                miss_budget[j] = self.shed_deadline_sec
-                miss_shed[j] = True
-                self._sheds += 1
-
-        # arena residencies are acquired only when something will actually
-        # execute: a fully cache-served slate must never pay acquire work
-        # (a cold acquire re-uploads whole families)
-        residencies = (
-            self._acquire_residencies(views, cached_views, token, shard_ids)
-            if miss_idx
-            else None
-        )
         # micro-batch the misses: one fused dispatch per admitted batch.
         # Ranking runs at the chunk-wide max top_k; each response is trimmed
         # to its own request's top_k afterwards — rank_documents is a total
@@ -450,6 +454,7 @@ class ServingFrontend:
                 admitted=chunk_admitted,
                 residencies=residencies,
                 defer=self.pipeline,
+                slots=miss_idx[lo:hi],
             )
             return lo, chunk_plans, chunk_admitted, t0, out
 

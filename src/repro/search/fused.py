@@ -44,8 +44,8 @@ Fragment sets are identical for every routing.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-import time
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -59,6 +59,8 @@ from ..core.postings import QueryStats, SearchResult
 from ..index.builder import IndexSet, POSTING_WIDTH
 from ..kernels.intersect import PAD, block_offsets, intersect_sorted
 from ..kernels.proximity import proximity_window
+from ..runtime import spans
+from ..runtime.spans import span
 
 __all__ = [
     "SegmentEvents",
@@ -103,40 +105,41 @@ def reset_dispatch_count() -> None:
 # phase attribution + compile accounting (DESIGN.md §13.5 benches)
 # ---------------------------------------------------------------------------
 
-# When a sink dict is installed, the serving paths attribute wall time to
-# the six phases of a batch, appended per batch in µs (DESIGN.md §15.3):
-#
-#   plan_us      host posting reads + segment extraction
-#   pack_us      host-side batch packing (or arena descriptor planning)
-#   h2d_us       ENQUEUE time of the input transfers (async; no barrier)
-#   dispatch_us  jit-call SUBMIT time (tracing/cache lookup + enqueue)
-#   compute_us   block_until_ready wait for the device program (only
-#                recorded when a sink is installed — production serving
-#                never inserts this barrier; under the two-deep pipeline it
-#                measures the NON-overlapped remainder of device time)
-#   readout_us   the fixed-shape D2H result-buffer copy + split
-#
-# The six sum to the serial batch wall time with no double-counting: every
-# timestamp closes one phase and opens the next.  The sink itself adds no
-# barriers beyond the compute_us wait.
-_PHASE_SINK: dict | None = None
+
+# the six §15.3 phase spans of a batch, with the key each is recorded under
+_PHASE_KEYS = {
+    "serve.plan": "plan_us",
+    "serve.pack": "pack_us",
+    "serve.h2d": "h2d_us",
+    "serve.dispatch": "dispatch_us",
+    "serve.device_wait": "compute_us",
+    "serve.readout": "readout_us",
+}
+
+
+class _PhaseSink:
+    """The span recorder ``collect_phases`` installs: µs per phase key,
+    other spans dropped."""
+
+    __slots__ = ("sink",)
+
+    def __init__(self, sink: dict):
+        self.sink = sink
+
+    def __call__(self, name: str, seconds: float) -> None:
+        key = _PHASE_KEYS.get(name)
+        if key is not None:
+            self.sink.setdefault(key, []).append(seconds * 1e6)
 
 
 def collect_phases(sink: dict | None) -> dict | None:
-    """Install (or clear, with ``None``) the phase-breakdown sink used by
-    ``benchmarks/run.py`` to attribute batch latency (plan / pack / h2d /
-    dispatch / compute / readout — the DESIGN.md §15.3 attribution).
-    Returns the previous sink."""
-    global _PHASE_SINK
-    prev, _PHASE_SINK = _PHASE_SINK, sink
-    return prev
-
-
-def _phase(sink: dict | None, name: str, t0: float) -> float:
-    now = time.perf_counter()
-    if sink is not None:
-        sink.setdefault(name, []).append((now - t0) * 1e6)
-    return now
+    """Install (or clear, with ``None``) a phase-breakdown sink: while it is
+    installed, each of the six §15.3 phase spans appends its wall time in
+    µs under its key as it closes (``plan_us`` / ``pack_us`` / ``h2d_us``
+    / ``dispatch_us`` / ``compute_us`` / ``readout_us``; ``compute_us`` is
+    the ``serve.device_wait`` span).  Returns the previous sink."""
+    prev = spans.set_recorder(None if sink is None else _PhaseSink(sink))
+    return prev.sink if isinstance(prev, _PhaseSink) else None
 
 
 def compile_count() -> int | None:
@@ -434,60 +437,58 @@ def plan_query_batch(
             return stats
         return stats[qi]
 
-    sink = _PHASE_SINK
-    t0 = time.perf_counter()
     segs: list[tuple[int, SegmentEvents]] = []
-    for qi, items in enumerate(work):
-        for item in items:
-            sub, index = item[0], item[1]
-            keys = item[2] if len(item) > 2 else None
-            se = extract_segment_events(
-                sub,
-                index,
-                keys=keys,
-                doc_len=doc_len,
-                stats=stat_for(qi),
-                intersect_device_threshold=intersect_device_threshold,
-            )
-            if se is not None:
-                segs.append((qi, se))
-    t0 = _phase(sink, "plan_us", t0)
+    with span("serve.plan"):
+        for qi, items in enumerate(work):
+            for item in items:
+                sub, index = item[0], item[1]
+                keys = item[2] if len(item) > 2 else None
+                se = extract_segment_events(
+                    sub,
+                    index,
+                    keys=keys,
+                    doc_len=doc_len,
+                    stats=stat_for(qi),
+                    intersect_device_threshold=intersect_device_threshold,
+                )
+                if se is not None:
+                    segs.append((qi, se))
     if not segs:
         return None
 
-    n_rows = sum(len(se.doc_ids) for _, se in segs)
-    n_events = sum(len(se.slot) for _, se in segs)
-    r_budget = bucket_pow2(n_rows, lo=8)
-    e_budget = bucket_pow2(n_events, lo=64)
-    l_budget = bucket_pow2(max(len(se.lemmas) for _, se in segs), lo=2)
-    k_budget = bucket_pow2(max(int(se.rank.max()) for _, se in segs) + 1, lo=4)
-    # position budget: bucketed from the last real event, NOT clamped to the
-    # caller's doc_len hint — long documents keep their fragments (the event
-    # path's cost barely depends on it; only the dense kernel path scatters
-    # [R, L, N] occupancy)
-    max_pos = max(int(se.pos.max()) for _, se in segs)
-    n_budget = bucket_pow2(max_pos + 1, lo=64)
+    with span("serve.pack", path="host"):
+        n_rows = sum(len(se.doc_ids) for _, se in segs)
+        n_events = sum(len(se.slot) for _, se in segs)
+        r_budget = bucket_pow2(n_rows, lo=8)
+        e_budget = bucket_pow2(n_events, lo=64)
+        l_budget = bucket_pow2(max(len(se.lemmas) for _, se in segs), lo=2)
+        k_budget = bucket_pow2(max(int(se.rank.max()) for _, se in segs) + 1, lo=4)
+        # position budget: bucketed from the last real event, NOT clamped to the
+        # caller's doc_len hint — long documents keep their fragments (the event
+        # path's cost barely depends on it; only the dense kernel path scatters
+        # [R, L, N] occupancy)
+        max_pos = max(int(se.pos.max()) for _, se in segs)
+        n_budget = bucket_pow2(max_pos + 1, lo=64)
 
-    events = np.full((e_budget, 3), -1, np.int32)
-    primary = np.zeros((e_budget,), np.int8)
-    postab = np.full((r_budget, l_budget, k_budget), n_budget, np.int32)
-    row_doc = np.full((r_budget,), -1, np.int32)
-    row_query = np.full((r_budget,), -1, np.int32)
-    mult = np.zeros((r_budget, l_budget), np.int32)
-    row = ev = 0
-    for qi, se in segs:
-        nd, ne = len(se.doc_ids), len(se.slot)
-        events[ev : ev + ne, 0] = se.slot + row
-        events[ev : ev + ne, 1] = se.pos
-        events[ev : ev + ne, 2] = se.lem
-        primary[ev : ev + ne] = se.primary
-        postab[se.slot + row, se.lem, se.rank] = se.pos
-        row_doc[row : row + nd] = se.doc_ids
-        row_query[row : row + nd] = qi
-        mult[row : row + nd, : len(se.mult)] = se.mult
-        row += nd
-        ev += ne
-    _phase(sink, "pack_us", t0)
+        events = np.full((e_budget, 3), -1, np.int32)
+        primary = np.zeros((e_budget,), np.int8)
+        postab = np.full((r_budget, l_budget, k_budget), n_budget, np.int32)
+        row_doc = np.full((r_budget,), -1, np.int32)
+        row_query = np.full((r_budget,), -1, np.int32)
+        mult = np.zeros((r_budget, l_budget), np.int32)
+        row = ev = 0
+        for qi, se in segs:
+            nd, ne = len(se.doc_ids), len(se.slot)
+            events[ev : ev + ne, 0] = se.slot + row
+            events[ev : ev + ne, 1] = se.pos
+            events[ev : ev + ne, 2] = se.lem
+            primary[ev : ev + ne] = se.primary
+            postab[se.slot + row, se.lem, se.rank] = se.pos
+            row_doc[row : row + nd] = se.doc_ids
+            row_query[row : row + nd] = qi
+            mult[row : row + nd, : len(se.mult)] = se.mult
+            row += nd
+            ev += ne
     return QueryBatchPlan(
         events=events,
         primary=primary,
@@ -918,87 +919,83 @@ def run_query_batch(
     if readout not in ("device", "host"):
         raise ValueError(f"unknown readout mode: {readout!r}")
     global _DISPATCHES
-    sink = _PHASE_SINK
-    t0 = time.perf_counter()
-    inputs = (
-        jnp.asarray(plan.events),
-        jnp.asarray(plan.primary),
-        jnp.asarray(plan.postab),
-        jnp.asarray(plan.row_doc),
-        jnp.asarray(plan.row_query),
-        jnp.asarray(plan.mult),
-    )
+    # enqueue time only: the transfers complete asynchronously, overlapped
+    # with submit — the premature block_until_ready(inputs) that used to sit
+    # here forced a full H2D sync inside the dispatch window
+    with span("serve.h2d"):
+        inputs = (
+            jnp.asarray(plan.events),
+            jnp.asarray(plan.primary),
+            jnp.asarray(plan.postab),
+            jnp.asarray(plan.row_doc),
+            jnp.asarray(plan.row_query),
+            jnp.asarray(plan.mult),
+        )
     if stats is not None:
         stats.h2d_bytes += (
             plan.events.nbytes + plan.primary.nbytes + plan.postab.nbytes
             + plan.row_doc.nbytes + plan.row_query.nbytes + plan.mult.nbytes
         )
-    # enqueue time only: the transfers complete asynchronously, overlapped
-    # with submit — the premature block_until_ready(inputs) that used to sit
-    # here forced a full H2D sync inside the dispatch window
-    t0 = _phase(sink, "h2d_us", t0)
-    out = fused_serve_batch(
-        *inputs,
-        max_distance=max_distance,
-        query_budget=plan.query_budget,
-        window_len=plan.doc_len,
-        top_k=top_k,
-        compute_dtype=compute_dtype,
-        use_kernel=use_kernel,
-    )
+    with span("serve.dispatch"):
+        out = fused_serve_batch(
+            *inputs,
+            max_distance=max_distance,
+            query_budget=plan.query_budget,
+            window_len=plan.doc_len,
+            top_k=top_k,
+            compute_dtype=compute_dtype,
+            use_kernel=use_kernel,
+        )
     _DISPATCHES += 1
     if stats is not None:
         stats.device_dispatches += 1
-    _phase(sink, "dispatch_us", t0)
 
     nq = plan.n_queries
 
     def finalize() -> FusedBatchResult:
-        t1 = time.perf_counter()
-        if sink is not None:
-            # bench-only barrier: bills device time to compute_us instead of
-            # whichever phase bracket happens to enclose the first fetch
+        # the device wait gets a span of its own, so it is never billed to
+        # whichever readout step first touches an output
+        with span("serve.device_wait"):
             jax.block_until_ready(out)
-            t1 = _phase(sink, "compute_us", t1)
-        if readout == "device":
-            buf = np.asarray(out["res"])
-            frag_rows, frag_offsets = _split_result_buffer(
-                buf, nq, plan.query_budget
-            )
-            result = FusedBatchResult(
-                frag_rows=frag_rows,
-                frag_offsets=frag_offsets,
-                top_docs=np.asarray(out["top_docs"])[:nq],
-                top_scores=np.asarray(out["top_scores"])[:nq],
-                n_fragments=np.asarray(out["n_fragments"])[:nq],
-            )
-        else:
-            # legacy host readout: one nonzero over the event batch (primary
-            # events carry one fragment per emitting position), then the
-            # two-tier host dedup — differential reference for §15.1
-            emit = np.asarray(out["emit"]) & (plan.primary > 0)
-            (hits,) = np.nonzero(emit)
-            starts = np.asarray(out["start"])[hits].astype(np.int64)
-            ends = plan.events[hits, 1].astype(np.int64)
-            rows = plan.events[hits, 0]
-            docs = plan.row_doc[rows].astype(np.int64)
-            q_of = plan.row_query[rows].astype(np.int64)
-            live = (q_of >= 0) & (q_of < nq)
-            u_q, u_doc, u_start, u_end = _dedup_fragments(
-                q_of[live], docs[live], starts[live], ends[live]
-            )
-            per_query: list[list[SearchResult]] = [[] for _ in range(nq)]
-            for qi, d, st, en in zip(
-                u_q.tolist(), u_doc.tolist(), u_start.tolist(), u_end.tolist()
-            ):
-                per_query[qi].append(SearchResult(doc_id=d, start=st, end=en))
-            result = FusedBatchResult(
-                per_query=per_query,
-                top_docs=np.asarray(out["top_docs"])[:nq],
-                top_scores=np.asarray(out["top_scores"])[:nq],
-                n_fragments=np.asarray(out["n_fragments"])[:nq],
-            )
-        _phase(sink, "readout_us", t1)
+        with span("serve.readout"):
+            if readout == "device":
+                buf = np.asarray(out["res"])
+                frag_rows, frag_offsets = _split_result_buffer(
+                    buf, nq, plan.query_budget
+                )
+                result = FusedBatchResult(
+                    frag_rows=frag_rows,
+                    frag_offsets=frag_offsets,
+                    top_docs=np.asarray(out["top_docs"])[:nq],
+                    top_scores=np.asarray(out["top_scores"])[:nq],
+                    n_fragments=np.asarray(out["n_fragments"])[:nq],
+                )
+            else:
+                # legacy host readout: one nonzero over the event batch (primary
+                # events carry one fragment per emitting position), then the
+                # two-tier host dedup — differential reference for §15.1
+                emit = np.asarray(out["emit"]) & (plan.primary > 0)
+                (hits,) = np.nonzero(emit)
+                starts = np.asarray(out["start"])[hits].astype(np.int64)
+                ends = plan.events[hits, 1].astype(np.int64)
+                rows = plan.events[hits, 0]
+                docs = plan.row_doc[rows].astype(np.int64)
+                q_of = plan.row_query[rows].astype(np.int64)
+                live = (q_of >= 0) & (q_of < nq)
+                u_q, u_doc, u_start, u_end = _dedup_fragments(
+                    q_of[live], docs[live], starts[live], ends[live]
+                )
+                per_query: list[list[SearchResult]] = [[] for _ in range(nq)]
+                for qi, d, st, en in zip(
+                    u_q.tolist(), u_doc.tolist(), u_start.tolist(), u_end.tolist()
+                ):
+                    per_query[qi].append(SearchResult(doc_id=d, start=st, end=en))
+                result = FusedBatchResult(
+                    per_query=per_query,
+                    top_docs=np.asarray(out["top_docs"])[:nq],
+                    top_scores=np.asarray(out["top_scores"])[:nq],
+                    n_fragments=np.asarray(out["n_fragments"])[:nq],
+                )
         return result
 
     if defer:
@@ -1019,53 +1016,54 @@ def _merge_results(
     array level — concatenate fragment columns, re-dedup with the two-tier
     host dedup — so a mixed batch never materializes ``SearchResult``
     objects; results that already carry ``per_query`` lists union as sets
-    (the same dedup)."""
+    (the same dedup).  Merging is part of the batch's readout span."""
     if len(results) == 1:
         return results[0]
-    scores = np.concatenate([r.top_scores for r in results], axis=1)
-    docs = np.concatenate([r.top_docs for r in results], axis=1)
-    order = np.argsort(-scores, axis=1, kind="stable")[:, :top_k]
-    top_docs = np.take_along_axis(docs, order, axis=1)
-    top_scores = np.take_along_axis(scores, order, axis=1)
-    n_fragments = sum(r.n_fragments for r in results)
-    if all(r.frag_offsets is not None and r._per_query is None for r in results):
-        q_col = np.concatenate(
-            [
-                np.repeat(
-                    np.arange(n_queries, dtype=np.int64),
-                    np.diff(r.frag_offsets),
-                )
-                for r in results
-            ]
-        )
-        rows = np.concatenate(
-            [r.frag_rows for r in results], dtype=np.int64, casting="unsafe"
-        ).reshape(-1, 3)
-        u_q, u_d, u_s, u_e = _dedup_fragments(
-            q_col, rows[:, 0], rows[:, 1], rows[:, 2]
-        )
-        counts = np.bincount(u_q, minlength=n_queries)
-        offsets = np.zeros((n_queries + 1,), np.int64)
-        np.cumsum(counts, out=offsets[1:])
+    with span("serve.readout"):
+        scores = np.concatenate([r.top_scores for r in results], axis=1)
+        docs = np.concatenate([r.top_docs for r in results], axis=1)
+        order = np.argsort(-scores, axis=1, kind="stable")[:, :top_k]
+        top_docs = np.take_along_axis(docs, order, axis=1)
+        top_scores = np.take_along_axis(scores, order, axis=1)
+        n_fragments = sum(r.n_fragments for r in results)
+        if all(r.frag_offsets is not None and r._per_query is None for r in results):
+            q_col = np.concatenate(
+                [
+                    np.repeat(
+                        np.arange(n_queries, dtype=np.int64),
+                        np.diff(r.frag_offsets),
+                    )
+                    for r in results
+                ]
+            )
+            rows = np.concatenate(
+                [r.frag_rows for r in results], dtype=np.int64, casting="unsafe"
+            ).reshape(-1, 3)
+            u_q, u_d, u_s, u_e = _dedup_fragments(
+                q_col, rows[:, 0], rows[:, 1], rows[:, 2]
+            )
+            counts = np.bincount(u_q, minlength=n_queries)
+            offsets = np.zeros((n_queries + 1,), np.int64)
+            np.cumsum(counts, out=offsets[1:])
+            return FusedBatchResult(
+                frag_rows=np.stack([u_d, u_s, u_e], axis=1).astype(np.int32),
+                frag_offsets=offsets,
+                top_docs=top_docs,
+                top_scores=top_scores,
+                n_fragments=n_fragments,
+            )
+        per_query: list[list[SearchResult]] = []
+        for qi in range(n_queries):
+            union: set[SearchResult] = set()
+            for r in results:
+                union.update(r.per_query[qi])
+            per_query.append(sorted(union))
         return FusedBatchResult(
-            frag_rows=np.stack([u_d, u_s, u_e], axis=1).astype(np.int32),
-            frag_offsets=offsets,
+            per_query=per_query,
             top_docs=top_docs,
             top_scores=top_scores,
             n_fragments=n_fragments,
         )
-    per_query: list[list[SearchResult]] = []
-    for qi in range(n_queries):
-        union: set[SearchResult] = set()
-        for r in results:
-            union.update(r.per_query[qi])
-        per_query.append(sorted(union))
-    return FusedBatchResult(
-        per_query=per_query,
-        top_docs=top_docs,
-        top_scores=top_scores,
-        n_fragments=n_fragments,
-    )
 
 
 def serve_query_batch(
@@ -1114,105 +1112,103 @@ def serve_query_batch(
             return stats
         return stats[qi]
 
-    sink = _PHASE_SINK
     host_work: list[list[tuple]] = [[] for _ in work]
     arena_items: list[tuple] = []
     arena_fallback: list[tuple[int, tuple]] = []
-    t0 = time.perf_counter()
-    for qi, items in enumerate(work):
-        for item in items:
-            sub, view = item[0], item[1]
-            res = residencies.get(id(view)) if residencies else None
-            if res is None:
-                host_work[qi].append(item)
-                continue
-            keys = (
-                list(item[2])
-                if len(item) > 2 and item[2] is not None
-                else select_keys(sub, view.fl)
-            )
-            st = stat_for(qi)
-            extents = []
-            for key in keys:
-                ext = res.lookup(key.components)
-                if ext is None:
-                    break
-                extents.append(ext)
-            if len(extents) < len(keys):
-                if st is not None:
-                    # per-key units, like arena_hits: every key of the item
-                    # is served by the host pack
-                    st.arena_misses += len(keys)
-                # carry the selected keys: the host pack accepts 3-tuples,
-                # so key selection is not recomputed for the fallback
-                host_work[qi].append((sub, view, keys))
-                continue
+    aplan = None
+    # the arena's whole host side, routing and descriptor planning, is its
+    # pack phase (there is no plan phase: no posting is read on the host)
+    with span("serve.pack", path="arena") if residencies else contextlib.nullcontext():
+        for qi, items in enumerate(work):
+            for item in items:
+                sub, view = item[0], item[1]
+                res = residencies.get(id(view)) if residencies else None
+                if res is None:
+                    host_work[qi].append(item)
+                    continue
+                keys = (
+                    list(item[2])
+                    if len(item) > 2 and item[2] is not None
+                    else select_keys(sub, view.fl)
+                )
+                st = stat_for(qi)
+                extents = []
+                for key in keys:
+                    ext = res.lookup(key.components)
+                    if ext is None:
+                        break
+                    extents.append(ext)
+                if len(extents) < len(keys):
+                    if st is not None:
+                        # per-key units, like arena_hits: every key of the item
+                        # is served by the host pack
+                        st.arena_misses += len(keys)
+                    # carry the selected keys: the host pack accepts 3-tuples,
+                    # so key selection is not recomputed for the fallback
+                    host_work[qi].append((sub, view, keys))
+                    continue
 
-            def account(hit=True, st=st, keys=keys, extents=extents):
-                # §11 accounting parity with the host pack: the arena path
-                # reads the same rows, just on the device.  ``hit=False``
-                # records an overflow fallback — the keys resolved but the
-                # batch executed on the host, which does its own counting.
-                if st is None:
-                    return
-                if not hit:
-                    st.arena_misses += len(keys)
-                    return
-                st.arena_hits += len(keys)
-                for ext in extents:
-                    st.postings_read += ext.n_rows
-                    st.bytes_read += ext.n_rows * 4 * POSTING_WIDTH.get(
-                        ext.family, 2
-                    )
+                def account(hit=True, st=st, keys=keys, extents=extents):
+                    # §11 accounting parity with the host pack: the arena path
+                    # reads the same rows, just on the device.  ``hit=False``
+                    # records an overflow fallback — the keys resolved but the
+                    # batch executed on the host, which does its own counting.
+                    if st is None:
+                        return
+                    if not hit:
+                        st.arena_misses += len(keys)
+                        return
+                    st.arena_hits += len(keys)
+                    for ext in extents:
+                        st.postings_read += ext.n_rows
+                        st.bytes_read += ext.n_rows * 4 * POSTING_WIDTH.get(
+                            ext.family, 2
+                        )
 
-            # provably-empty short-circuits, mirroring the host pack
-            # (extract_segment_events returning None):
-            if (
-                not keys
-                or all(e.n_rows == 0 for e in extents)
-                or (len(keys) >= 2 and any(e.n_rows == 0 for e in extents))
-            ):
-                account()
-                if st is not None:
-                    st.empty_subqueries += 1
-                continue
-            arena_items.append((qi, sub, keys, extents, res))
-            # fallback bookkeeping: the (sub, view, keys) item for host
-            # re-queueing (keys carried, not recomputed), the accounting
-            # thunk applied ONLY if the arena plan succeeds (on
-            # ArenaOverflow the host pack does its own counting — no double
-            # charge, no phantom arena_hits)
-            arena_fallback.append((qi, (sub, view, keys), account))
+                # provably-empty short-circuits, mirroring the host pack
+                # (extract_segment_events returning None):
+                if (
+                    not keys
+                    or all(e.n_rows == 0 for e in extents)
+                    or (len(keys) >= 2 and any(e.n_rows == 0 for e in extents))
+                ):
+                    account()
+                    if st is not None:
+                        st.empty_subqueries += 1
+                    continue
+                arena_items.append((qi, sub, keys, extents, res))
+                # fallback bookkeeping: the (sub, view, keys) item for host
+                # re-queueing (keys carried, not recomputed), the accounting
+                # thunk applied ONLY if the arena plan succeeds (on
+                # ArenaOverflow the host pack does its own counting — no double
+                # charge, no phantom arena_hits)
+                arena_fallback.append((qi, (sub, view, keys), account))
+
+        if arena_items:
+            try:
+                aplan = plan_arena_batch(arena_items, n_queries=len(work))
+            except ArenaOverflow:
+                for qi, item3, account in arena_fallback:
+                    account(hit=False)
+                    host_work[qi].append(item3)
+            else:
+                for _qi, _item3, account in arena_fallback:
+                    account()
 
     results: list[FusedBatchResult] = []
-    if arena_items:
-        try:
-            aplan = plan_arena_batch(arena_items, n_queries=len(work))
-        except ArenaOverflow:
-            aplan = None
-            for qi, item3, account in arena_fallback:
-                account(hit=False)
-                host_work[qi].append(item3)
-        if aplan is not None:
-            for _qi, _item3, account in arena_fallback:
-                account()
-        # the arena's whole host side — routing + descriptor planning —
-        # is the pack phase (there is no plan phase: no posting is read)
-        t0 = _phase(sink, "pack_us", t0)
-        if aplan is not None:
-            results.append(
-                run_arena_batch(
-                    aplan,
-                    max_distance=max_distance,
-                    top_k=top_k,
-                    use_kernel=use_kernel,
-                    stats=batch_stats,
-                    phases=sink,
-                    readout=readout,
-                    defer=defer,
-                )
+    if aplan is not None:
+        results.append(
+            run_arena_batch(
+                aplan,
+                max_distance=max_distance,
+                top_k=top_k,
+                use_kernel=use_kernel,
+                stats=batch_stats,
+                readout=readout,
+                defer=defer,
             )
-            _DISPATCHES += 1
+        )
+        _DISPATCHES += 1
     if any(host_work):
         hplan = plan_query_batch(
             host_work,

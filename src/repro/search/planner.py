@@ -47,6 +47,7 @@ from ..core.keys import (
 from ..core.lemma import FLList, Lemmatizer, LemmaType
 from ..core.postings import QueryStats
 from ..index.builder import IndexSet
+from ..runtime.spans import span
 from .fused import serve_query_batch
 from .relevance import rank_documents
 
@@ -291,6 +292,7 @@ def execute_plans(
     admitted: Sequence[Sequence[SubqueryPlan]] | None = None,
     residencies: dict | None = None,
     defer: bool = False,
+    slots: Sequence[int] | None = None,
 ) -> list:
     """Execute a batch of plans as ONE fused device dispatch (§5 stage 3–4).
 
@@ -311,10 +313,15 @@ def execute_plans(
     the readout and builds the responses — the DESIGN.md §15.2 hook the
     frontend's two-deep pipeline uses to overlap batch N's compute with
     batch N+1's plan/pack/H2D.
+
+    ``slots[qi]`` is plan ``qi``'s index in the caller's slate, the ``slot``
+    of its ``frontend.rank`` span (default: ``qi``).
     """
     from .engine import QueryResponse, RankedDoc
 
     t0 = time.perf_counter()
+    if slots is None:
+        slots = range(len(plans))
     if admitted is None:
         admitted = [plan.executable() for plan in plans]
     per_stats = [QueryStats() for _ in plans]
@@ -346,26 +353,29 @@ def execute_plans(
         elapsed = time.perf_counter() - t0
         responses = []
         for qi, plan in enumerate(plans):
-            fragments = result.per_query[qi]
-            docs = [
-                RankedDoc(doc_id=d, score=s, fragments=f)
-                for d, s, f in rank_documents(fragments, top_k=top_k)
-            ]
-            st = per_stats[qi]
-            st.results = len(fragments)
-            st.pruned_subqueries = plan.n_pruned
-            n_admitted = len(admitted[qi])
-            st.skipped_subqueries = len(plan.executable()) - n_admitted
-            st.partial = st.skipped_subqueries > 0
-            st.elapsed_sec = elapsed  # batch wall time (one shared dispatch)
-            responses.append(
-                QueryResponse(
-                    query=plan.query,
-                    docs=docs,
-                    stats=st,
-                    n_subqueries=len(plan.subqueries),
+            # one span per request: its ranking and response build (the
+            # first also materializes the batch's fragment lists)
+            with span("frontend.rank", slot=slots[qi]):
+                fragments = result.per_query[qi]
+                docs = [
+                    RankedDoc(doc_id=d, score=s, fragments=f)
+                    for d, s, f in rank_documents(fragments, top_k=top_k)
+                ]
+                st = per_stats[qi]
+                st.results = len(fragments)
+                st.pruned_subqueries = plan.n_pruned
+                n_admitted = len(admitted[qi])
+                st.skipped_subqueries = len(plan.executable()) - n_admitted
+                st.partial = st.skipped_subqueries > 0
+                st.elapsed_sec = elapsed  # batch wall time (one shared dispatch)
+                responses.append(
+                    QueryResponse(
+                        query=plan.query,
+                        docs=docs,
+                        stats=st,
+                        n_subqueries=len(plan.subqueries),
+                    )
                 )
-            )
         return responses
 
     if defer:

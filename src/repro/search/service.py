@@ -52,6 +52,7 @@ from typing import Sequence
 
 from ..core.postings import QueryStats
 from ..runtime.clock import SystemClock
+from ..runtime.spans import span
 from .engine import QueryResponse
 from .frontend import SearchRequest, ServingFrontend
 
@@ -138,12 +139,15 @@ class Ticket:
 
 
 class _Inflight:
-    """One launched batch: the replica it occupies, its tickets in
+    """One launched batch: its id, the replica it occupies, its tickets in
     admission order, and the deferred finalize from ``submit_many``."""
 
-    __slots__ = ("replica", "tickets", "finalize", "launched_at")
+    __slots__ = ("batch", "replica", "tickets", "finalize", "launched_at")
 
-    def __init__(self, replica: int, tickets: list[Ticket], finalize, launched_at: float):
+    def __init__(
+        self, batch: int, replica: int, tickets: list[Ticket], finalize, launched_at: float
+    ):
+        self.batch = batch
         self.replica = replica
         self.tickets = tickets
         self.finalize = finalize
@@ -230,6 +234,7 @@ class ServiceDaemon:
         self._shed_queue = 0
         self._failed = 0
         self._batches = 0
+        self._next_batch = 0  # the id of the next batch launched
         self._batched = 0
         self._queue_peak = 0
         self._occupancy: dict[int, int] = {}
@@ -309,32 +314,35 @@ class ServiceDaemon:
                 take = min(cap, len(self._queue))
                 tickets = [self._queue.popleft() for _ in range(take)]
                 self._busy[idx] = True
+                batch = self._next_batch
+                self._next_batch += 1
             # deadline shrinking + submit happen OUTSIDE the lock: planning
             # and the device enqueue must not block concurrent admission
-            now = self.clock.now()
-            slate: list[SearchRequest] = []
-            for t in tickets:
-                wait = max(0.0, now - t.enqueued_at)
-                t.queue_wait_sec = wait
-                d = t.request.deadline_sec
-                eff = None if d is None else max(0.0, float(d) - wait)
-                t.effective_deadline_sec = eff
-                t.replica = idx
-                t.batch_size = len(tickets)
-                slate.append(
-                    SearchRequest(
-                        query=t.request.query,
-                        top_k=t.request.top_k,
-                        deadline_sec=eff,
+            with span("daemon.launch", batch=batch, first_seq=tickets[0].seq, n=len(tickets)):
+                now = self.clock.now()
+                slate: list[SearchRequest] = []
+                for t in tickets:
+                    wait = max(0.0, now - t.enqueued_at)
+                    t.queue_wait_sec = wait
+                    d = t.request.deadline_sec
+                    eff = None if d is None else max(0.0, float(d) - wait)
+                    t.effective_deadline_sec = eff
+                    t.replica = idx
+                    t.batch_size = len(tickets)
+                    slate.append(
+                        SearchRequest(
+                            query=t.request.query,
+                            top_k=t.request.top_k,
+                            deadline_sec=eff,
+                        )
                     )
-                )
-            try:
-                finalize = replica.submit_many(slate)
-            except Exception as exc:
-                self._fail_batch(idx, tickets, exc)
-                raise
+                try:
+                    finalize = replica.submit_many(slate)
+                except Exception as exc:
+                    self._fail_batch(idx, tickets, exc)
+                    raise
             with self._lock:
-                self._inflight.append(_Inflight(idx, tickets, finalize, now))
+                self._inflight.append(_Inflight(batch, idx, tickets, finalize, now))
                 self._batches += 1
                 self._batched += len(tickets)
                 self._per_replica_batches[idx] += 1
@@ -348,19 +356,20 @@ class ServiceDaemon:
             inf = self._inflight.popleft()
         # the blocking device readout runs OUTSIDE the lock: this is the
         # window in which submit() keeps admitting — continuous batching
-        try:
-            responses = inf.finalize()
-        except Exception as exc:
-            self._fail_batch(inf.replica, inf.tickets, exc)
-            raise
-        now = self.clock.now()
-        with self._work:
-            for ticket, resp in zip(inf.tickets, responses):
-                ticket.latency_sec = max(0.0, now - ticket.enqueued_at)
-                ticket._complete(resp)
-            self._busy[inf.replica] = False
-            self._completed += len(inf.tickets)
-            self._work.notify_all()
+        with span("daemon.retire", batch=inf.batch):
+            try:
+                responses = inf.finalize()
+            except Exception as exc:
+                self._fail_batch(inf.replica, inf.tickets, exc)
+                raise
+            now = self.clock.now()
+            with self._work:
+                for ticket, resp in zip(inf.tickets, responses):
+                    ticket.latency_sec = max(0.0, now - ticket.enqueued_at)
+                    ticket._complete(resp)
+                self._busy[inf.replica] = False
+                self._completed += len(inf.tickets)
+                self._work.notify_all()
         return True
 
     def _fail_batch(self, replica: int, tickets: list[Ticket], exc: BaseException) -> None:

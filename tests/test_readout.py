@@ -11,8 +11,9 @@ Pins the contracts the DESIGN.md §15 refactor introduced:
   fallback (instead of silently overflowing) when the packed key cannot
   hold the value ranges.
 * **Phase-timer schema** — one instrumented batch produces exactly the six
-  §15.3 phases, each bracket non-negative and summing to at most the serial
-  batch wall time (no double-counting).
+  §15.3 phase spans, each non-negative and summing to at most the serial
+  batch wall time (no double-counting); clearing the sink removes the
+  span recorder.
 * **Deferred dispatch** — ``defer=True`` returns a ``PendingBatch`` whose
   idempotent ``result()`` equals the eager call's result.
 * **Pipelined frontend** — the §15.2 two-deep driver returns byte-identical
@@ -169,6 +170,18 @@ def test_phase_schema_and_no_double_counting(corpus):
     # disjoint brackets: the phase sum cannot exceed the measured wall time
     # (equality up to the unbracketed merge/return tail)
     assert sum(sum(v) for v in phases.values()) <= wall * 1e6 + 1.0
+
+
+def test_clearing_the_phase_sink_leaves_no_recorder(corpus):
+    from repro.runtime import spans
+
+    _, idx, work = corpus
+    phases: dict = {}
+    assert fused.collect_phases(phases) is None
+    assert fused.collect_phases(None) is phases
+    serve_query_batch(work, max_distance=idx.max_distance)
+    assert phases == {}
+    assert spans.set_recorder(None) is None
 
 
 # ---------------------------------------------------------------------------
