@@ -39,11 +39,13 @@ generation** and does the gather/pack there:
   ``kernels/gather.py`` scalar-prefetch block gather slices the arena, then
   on-device sorts rebuild exactly the host pack's event pipeline — Step-1
   document alignment (distinct-key counting per candidate doc), cross-key
-  event dedup, Step-2 multiplicity gate, the event-centric rank cover
-  (binary search over the (row, lemma, pos)-sorted stream — the ``postab``
-  content of §9.1 without materializing the ``[R, L, K]`` table, so no
-  data-dependent K budget exists), then the SAME §14 scoring and per-query
-  top-k stages as ``fused_serve_batch``.
+  event dedup, then the Step-2 multiplicity gate and the event-centric rank
+  cover in one loop over lemma slots: each count is a difference of prefix
+  counts over the (row, pos, lemma)-sorted stream, and each fragment start
+  one gather from the same events sorted by (row, lemma, pos) — the
+  ``postab`` content of §9.1 without materializing the ``[R, L, K]`` table,
+  so no data-dependent K budget exists — then the SAME §14 scoring and
+  per-query top-k stages as ``fused_serve_batch``.
 
 Exactness contract: arena-path fragment sets are identical to the host-pack
 path and therefore to the §10 oracle — the same dedup, the same Step-1/
@@ -785,12 +787,14 @@ def arena_serve_batch(
              candidate-row ids + Step-1 document alignment (distinct-key
              counting over each key's slot-0 stream keeps docs present in
              EVERY key iterator);
-    stage 2  cross-key event dedup to one (doc, pos, lemma) + the Step-2
-             multiplicity gate — exactly the host pack's ``np.unique`` +
-             ``bincount`` gates;
-    stage 3  event-centric rank cover: binary search over the (row, lemma,
-             pos)-sorted stream replaces the §9 ``postab`` gather (same
-             rank identity, no ``[R, L, K]`` materialization);
+    stage 2  cross-key event dedup to one (doc, pos, lemma) — exactly the
+             host pack's ``np.unique``;
+    stage 3  Step-2 gate + event-centric rank cover, one loop step per
+             lemma slot: per-row and per-event lemma counts are differences
+             of prefix counts over the (row, pos, lemma)-sorted stream, and
+             the fragment start is one gather from the (row, lemma,
+             pos)-sorted stream, which replaces the §9 ``postab`` gather
+             (same rank identity, no ``[R, L, K]`` materialization);
     stage 4  §14 scoring + per-query top-k — the same stages as
              ``fused_serve_batch``.
 
@@ -904,7 +908,7 @@ def arena_serve_batch(
     need = n_keys[row_seg_c]
     row_pass = row_used & ((need < 2) | (key_count >= need))
 
-    # ---- stage 2: dedup to one (doc, pos, lemma) + Step-2 gate ------------
+    # ---- stage 2: dedup to one (doc, pos, lemma) ---------------------------
     keep = fin1 & em_s & (pos_s < n_budget) & row_pass[row_idc]
     comp = (((row_idc << nb) | pos_s) << lb) | lem_s
     comp = jnp.where(keep, comp, _I32_MAX)
@@ -915,81 +919,93 @@ def arena_serve_batch(
     lem2 = comp & (lemma_budget - 1)
     pos2 = (comp >> lb) & (n_budget - 1)
     row2 = jnp.clip(comp >> (lb + nb), 0, row_budget - 1)
+    # `comp` groups rows contiguously: [c_lo, c_hi) is row r's run, shared
+    # by the stage-3 counts and the stage-4 per-row reductions
+    crow = jnp.where(fin, comp >> (lb + nb), row_budget)
+    c_lo = _binary_search(crow, r_iota, right=False)
+    c_hi = _binary_search(crow, r_iota, right=True)
 
-    # ---- stage 3: the (row, lemma, pos)-sorted stream IS the §9.1 postab --
+    # ---- stage 3: Step-2 gate + rank cover by prefix counts ---------------
+    # `cov`, the deduped events re-sorted by (row, lemma, pos), IS the §9.1
+    # postab.  `comp` holds the same unique events sorted by (row, pos,
+    # lemma), so with C_l the exclusive prefix count of unique lemma-l
+    # events over `comp`, every count a search of `cov` would find is a
+    # difference of prefix counts:
+    #   row r holds C_l[c_hi] - C_l[c_lo] of them (the Step-2 count);
+    #   C_l[g_hi] - C_l[c_lo] lie at or before an event's position, g_hi
+    #     ending the event's (row, pos) run, so lemmas that sort after the
+    #     event at its own position count too (a word may carry several);
+    #   group (r, l) starts in `cov` after the unique events of rows before
+    #     r and those of row r's lemma slots before l (carried as `cov_lo`).
     cov = (((row2 << lb) | lem2) << nb) | pos2
     cov = jnp.where(uniq, cov, _I32_MAX)
     cov = jnp.sort(cov)
-    # per-(row, lemma) group bounds once (small), reused by Step-2 and the
-    # per-event cover; `cov` holds deduped events only, so range sizes are
-    # exactly the distinct-position counts the host pack bincounts
+    pp = comp >> lb  # (row, pos) run id
+    e_iota = jnp.arange(e, dtype=jnp.int32)
+    next_pp = jnp.concatenate([pp[1:], jnp.array([-1], jnp.int32)])
+    g_hi = jax.lax.cummin(jnp.where(pp != next_pp, e_iota, e - 1), reverse=True) + 1
+    ev_lo = c_lo[row2]
+    cum_uniq = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(uniq.astype(jnp.int32))]
+    )
     # One loop step per lemma slot over 1-D [R] / [E] streams: a [R, L] or
     # [E, L] tensor would pad its narrow lemma dim to 128 lanes on the TPU
     # (stage 0), and XLA lays the transposed [L, E] gathers out the same way.
     mult_t = mult.T  # [L, S] (0 = unused slot, trivially passes)
 
-    def lemma_rows(l):
-        """Per-row ``(group base key, first index in cov, mult)`` of lemma
-        slot ``l``."""
-        grp = ((r_iota << lb) | l) << nb
-        return grp, _binary_search(cov, grp, right=False), mult_t[l][row_seg_c]
-
-    def row_gate(l, ok):
-        grp, lo, m = lemma_rows(l)
-        cnt = _binary_search(cov, grp | (n_budget - 1), right=True) - lo
-        return ok & (cnt >= m)
-
-    ok_row = jax.lax.fori_loop(0, lemma_budget, row_gate, row_used)
-    live = uniq & ok_row[row2]
-
     # event-centric rank cover (§9.3 identity): for event (row, pos) and
     # lemma l, cnt = occurrences of l at or before pos; the fragment start
-    # is the mult-th latest, gathered straight from the sorted stream
-    def event_cover(l, carry):
-        start, all_have, any_active = carry
-        _grp, lo_r, m_r = lemma_rows(l)
-        grp_e = ((row2 << lb) | l) << nb
-        hi_e = _binary_search(cov, grp_e | pos2, right=True)
-        lo_e = lo_r[row2]
-        cnt = hi_e - lo_e
+    # is the mult-th latest, gathered straight from `cov`
+    def lemma_step(l, carry):
+        ok_row, cov_lo, start, all_have, any_active = carry
+        c = jnp.concatenate(
+            [
+                jnp.zeros((1,), jnp.int32),
+                jnp.cumsum((uniq & (lem2 == l)).astype(jnp.int32)),
+            ]
+        )
+        cnt_r = c[c_hi] - c[c_lo]
+        m_r = mult_t[l][row_seg_c]
+        cnt = c[g_hi] - c[ev_lo]
         mult_e = m_r[row2]
         active = mult_e > 0
         have = cnt >= mult_e
-        sel = jnp.clip(lo_e + cnt - mult_e, 0, e - 1)
+        sel = jnp.clip(cov_lo[row2] + cnt - mult_e, 0, e - 1)
         p_sel = cov[sel] & (n_budget - 1)
         p_sel = jnp.where(active & have, p_sel, n_budget)
         return (
+            ok_row & (cnt_r >= m_r),
+            cov_lo + cnt_r,
             jnp.minimum(start, p_sel),
             all_have & (have | ~active),
             any_active | active,
         )
 
-    start, all_have, any_active = jax.lax.fori_loop(
+    ok_row, _, start, all_have, any_active = jax.lax.fori_loop(
         0,
         lemma_budget,
-        event_cover,
+        lemma_step,
         (
+            row_used,
+            cum_uniq[c_lo],
             jnp.full((e,), n_budget, jnp.int32),
             jnp.ones((e,), jnp.bool_),
             jnp.zeros((e,), jnp.bool_),
         ),
     )
+    live = uniq & ok_row[row2]
     covered = all_have & any_active
     emit = live & covered & (start < n_budget) & (pos2 - start < window)
     start = jnp.where(emit, start, pos2)
 
     # ---- stage 4: §14 scoring + per-query top-k (as fused_serve_batch) ----
-    pp = comp >> lb
     prev_pp = jnp.concatenate([jnp.array([-1], jnp.int32), pp[:-1]])
     primary = fin & (pp != prev_pp)
     emit_primary = emit & primary
     span = (pos2 - start).astype(jnp.float32)
     contrib = jnp.where(emit_primary, 1.0 / (span + 1.0) ** 2, 0.0)
-    # per-row reductions via prefix sums over the row-sorted stream (`comp`
-    # groups rows contiguously) — no [E]->[R] scatters on the hot path
-    crow = jnp.where(fin, comp >> (lb + nb), row_budget)
-    c_lo = _binary_search(crow, r_iota, right=False)
-    c_hi = _binary_search(crow, r_iota, right=True)
+    # per-row reductions via prefix sums over the row-sorted stream — no
+    # [E]->[R] scatters on the hot path
     cum_scores = jnp.concatenate(
         [jnp.zeros((1,), jnp.float32), jnp.cumsum(contrib)]
     )

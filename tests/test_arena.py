@@ -14,10 +14,18 @@ from repro.core.combiner import se24_combiner
 from repro.core.keys import Subquery, expand_subqueries, select_keys
 from repro.core.postings import QueryStats
 from repro.index import DocumentStore, build_indexes, synthesize_corpus
+from repro.index.corpus import Document
 from repro.index.incremental import IncrementalIndexer, generation_token
 from repro.kernels.gather import ARENA_BLOCK, gather_blocks, gather_blocks_ref
 from repro.search import fused
-from repro.search.arena import PostingArena, plan_arena_batch
+from repro.search.arena import (
+    PostingArena,
+    _device_args,
+    _static_kwargs,
+    arena_serve_batch,
+    plan_arena_batch,
+    run_arena_batch,
+)
 from repro.search.distributed import ShardedSearchService
 from repro.search.engine import SearchEngine
 from repro.search.frontend import ServingFrontend
@@ -146,6 +154,134 @@ def test_sharded_service_arena_with_dead_shards(small_corpus):
             fa = {(d.doc_id, f.start, f.end) for d in a.docs for f in d.fragments}
             fh = {(d.doc_id, f.start, f.end) for d in h.docs for f in d.fragments}
             assert fa == fh
+
+
+# ---------------------------------------------------------------------------
+# stage 3, the Step-2 gate and the rank cover: against a plain recount over
+# the program's own sorted stream, the host pack and the oracle
+# ---------------------------------------------------------------------------
+
+_COVER_TEXTS = [
+    "to be or not to be that is the question",
+    "to be or not",  # every key of "to be or not to be", each lemma once
+    "who are you who are you to be",  # "are": lemmas are + be at one position
+    "you are who you are and to be is to be",
+    "to you who are",  # "are" stands for be and are, and ends a fragment
+    "the time of war",  # the corpus's only "war"
+]
+
+# (queries, lemma_budget, what the batch must contain)
+_COVER_CASES = {
+    "multiplicity": (["to be or not to be"], 4, ("mult_2", "step2_fails")),
+    "multi_lemma_position": (["to be who you are"], 8, ("two_lemmas_one_pos",)),
+    "budget_2": (["to be to be"], 2, ("mult_2",)),
+    "single_event": (["war"], 2, ("single_event",)),
+}
+
+
+def _recount_cover(out: dict, plan, max_distance: int) -> tuple:
+    """Stage 3 of the arena program recounted in plain Python over its own
+    (row, pos, lemma)-sorted ``comp`` stream: per-row lemma counts gate
+    Step-2, then every unique event of a passing row takes, per active
+    lemma, the mult-th latest position at or before its own.  Returns the
+    expected ``emit`` and ``start`` and the facts the batch exhibits."""
+    nb = (plan.n_budget - 1).bit_length()
+    lb = max((plan.lemma_budget - 1).bit_length(), 1)
+    comp = out["comp"].astype(np.int64)
+    fin = comp < np.iinfo(np.int32).max
+    uniq = fin & np.r_[True, comp[1:] != comp[:-1]]
+    pp = comp >> lb
+    primary = fin & np.r_[True, pp[1:] != pp[:-1]]
+    row, pos, lem = comp >> (lb + nb), pp & (plan.n_budget - 1), comp & (plan.lemma_budget - 1)
+    # one work item per query: a row's segment is its query's
+    seg_of = {int(q): s for s, q in enumerate(plan.seg_query) if q >= 0}
+    occ: dict = {}  # (row, lemma) -> positions, ascending
+    lemmas_at: dict = {}  # (row, pos) -> lemma slots
+    for r, p, l in zip(row[uniq].tolist(), pos[uniq].tolist(), lem[uniq].tolist()):
+        occ.setdefault((r, l), []).append(p)
+        lemmas_at.setdefault((r, p), set()).add(l)
+    mult = {r: plan.mult[seg_of[int(out["row_query"][r])]] for r in set(row[fin].tolist())}
+    passes = {
+        r: all(len(occ.get((r, l), ())) >= m for l, m in enumerate(m_r))
+        for r, m_r in mult.items()
+    }
+    emit = np.zeros(comp.shape, bool)
+    start = pos.copy()
+    for i in np.flatnonzero(uniq):
+        r, p = int(row[i]), int(pos[i])
+        if not passes[r]:
+            continue
+        firsts = []
+        for l, m in enumerate(mult[r].tolist()):
+            if m == 0:
+                continue
+            before = [q for q in occ.get((r, l), ()) if q <= p]
+            if len(before) < m:
+                break
+            firsts.append(before[-m])
+        else:
+            if firsts and p - min(firsts) <= 2 * max_distance:
+                emit[i], start[i] = True, min(firsts)
+    facts = {
+        "mult_2": int(plan.mult.max()) >= 2,
+        "step2_fails": not all(passes.values()),
+        "two_lemmas_one_pos": any(len(ls) > 1 for ls in lemmas_at.values()),
+        "single_event": int(fin.sum()) == 1,
+    }
+    return emit & primary, start.astype(np.int32), facts
+
+
+@pytest.mark.parametrize("tier", ["pack32", "argsort"])
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("case", sorted(_COVER_CASES))
+def test_arena_rank_cover_exact(lemmatizer, case, use_kernel, tier):
+    """The stage-3 prefix counts give the exact §9.3 cover: duplicate query
+    lemmas, two lemma slots at one position, rows that pass Step-1 and fail
+    Step-2, a one-event batch, lemma budgets 2 to 8, in both sort tiers
+    (wide doc ids force ``argsort``) and both gather forms."""
+    queries, lemma_budget, facts_needed = _COVER_CASES[case]
+    stride = 1 << 19 if tier == "argsort" else 1
+    store = DocumentStore.from_documents(
+        [
+            Document(doc_id=i * stride + 3, text=t, lemma_stream=lemmatizer.lemmatize_text(t))
+            for i, t in enumerate(_COVER_TEXTS)
+        ],
+        lemmatizer,
+    )
+    idx = build_indexes(store, sw_count=10_000, fu_count=0, max_distance=3)
+    subs = [s for q in queries for s in expand_subqueries(q, lemmatizer)]
+    _, res = _residency(idx)
+    res = res[id(idx)]
+    items = []
+    for qi, sub in enumerate(subs):
+        keys = select_keys(sub, idx.fl)
+        items.append((qi, sub, keys, [res.lookup(k.components) for k in keys], res))
+    plan = plan_arena_batch(items, n_queries=len(subs))
+    assert (plan.tier, plan.lemma_budget) == (tier, lemma_budget)
+
+    args, _ = _device_args(plan, use_kernel)
+    out = arena_serve_batch(
+        *args, **_static_kwargs(plan, max_distance=3, top_k=16, use_kernel=use_kernel)
+    )
+    out = {k: np.asarray(v) for k, v in out.items()}
+    emit, start, facts = _recount_cover(out, plan, max_distance=3)
+    assert all(facts[f] for f in facts_needed), facts
+    np.testing.assert_array_equal(out["emit"], emit)
+    np.testing.assert_array_equal(out["start"], start)
+
+    got = run_arena_batch(plan, max_distance=3, top_k=16, use_kernel=use_kernel)
+    host = fused.serve_query_batch([[(s, idx)] for s in subs], max_distance=3, top_k=16)
+    np.testing.assert_array_equal(got.n_fragments, host.n_fragments)
+    # the top-k width is min(top_k, row budget), which differs between paths
+    k = min(got.top_docs.shape[1], host.top_docs.shape[1])
+    for r in (got, host):
+        assert (r.top_docs[:, k:] == -1).all() and np.isneginf(r.top_scores[:, k:]).all()
+    np.testing.assert_array_equal(got.top_docs[:, :k], host.top_docs[:, :k])
+    np.testing.assert_allclose(got.top_scores[:, :k], host.top_scores[:, :k], rtol=1e-6)
+    for qi, sub in enumerate(subs):
+        expected, _ = se24_combiner(sub, idx)
+        assert set(got.per_query[qi]) == set(host.per_query[qi]) == set(expected)
+    assert sum(map(len, got.per_query)) > 0
 
 
 # ---------------------------------------------------------------------------
